@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import functools
+import os
 import time
 from typing import List, Optional
 
@@ -51,6 +52,26 @@ def record_manifest(suite: str, config_dict: dict, *,
                       "obs_schema": OBS_SCHEMA, "wall_s": wall_s,
                       "obs": obs, "kernel_plan": kernel_plan,
                       "nulls": reasons})
+
+
+#: label of every report a CPU-rehearsal child writes: its numbers come
+#: from forced host devices, never from a chip.
+CPU_REHEARSAL = {"platform": "cpu",
+                 "note": "CPU rehearsal on forced host devices; "
+                         "no number here is a device measurement"}
+
+
+def cpu_child_env() -> dict:
+    """Environment for a suite's child process that forces host devices.
+
+    Pinned to ``JAX_PLATFORMS=cpu``: a chip belongs to one process, and
+    the runner's own process may already hold it, so a child that
+    reached for it would fail or hang.
+    """
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
 
 
 @functools.lru_cache(maxsize=None)

@@ -8,8 +8,10 @@ the manifest records. Claim checks:
   * label parity — the Pallas fused round must produce labels
     bit-identical to the ref kernels (the dispatch plane's core
     contract, `scripts/smoke_kernels.py` proves it across engines);
-  * every traced fit must surface a non-null utilization gauge and a
-    resolved `KernelPlan` on its outcome — no unexplained nulls.
+  * every traced fit must surface a resolved `KernelPlan` on its
+    outcome, and a utilization gauge exactly when its device has
+    published peaks (`roofline.analysis.PEAKS`); otherwise the reason
+    is recorded — no unexplained nulls.
 
 Run standalone (`python -m benchmarks.kernels`) or via
 `python -m benchmarks.run --suite kernels` (which additionally writes
@@ -20,10 +22,12 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
+import jax
 import numpy as np
 
 from benchmarks import common
 from repro import api
+from repro.roofline.analysis import peaks_for
 
 ART = Path(__file__).resolve().parent.parent / "artifacts" / "bench"
 BACKENDS = ("ref", "pallas")
@@ -81,18 +85,28 @@ def main(quick: bool = True):
         "pallas labels bit-equal to ref",
         bool(np.array_equal(results["pallas"]["labels"],
                             results["ref"]["labels"])))
+    kind = jax.devices()[0].device_kind
+    has_peaks = peaks_for(kind) is not None
     for backend in BACKENDS:
+        if not has_peaks:
+            results[backend]["nulls"] = {
+                "fit_roofline_utilization":
+                    f"no published peaks for device_kind {kind!r}"}
         ok &= common.check(
-            f"{backend}: roofline utilization recorded",
-            results[backend]["fit_roofline_utilization"] is not None)
+            f"{backend}: roofline utilization recorded iff the device "
+            f"has peaks",
+            (results[backend]["fit_roofline_utilization"] is not None)
+            == has_peaks)
         ok &= common.check(
             f"{backend}: resolved kernel plan on the outcome",
             (results[backend]["kernel_plan"] or {}).get("backend")
             == backend)
     ART.mkdir(parents=True, exist_ok=True)
-    (ART / "kernels.json").write_text(json.dumps(
-        {b: {kk: v for kk, v in r.items() if kk != "labels"}
-         for b, r in results.items()}, indent=1))
+    report = {b: {kk: v for kk, v in r.items() if kk != "labels"}
+              for b, r in results.items()}
+    report["device"] = {"platform": jax.devices()[0].platform,
+                        "kind": kind}
+    (ART / "kernels.json").write_text(json.dumps(report, indent=1))
     return ok
 
 

@@ -198,8 +198,9 @@ def _spawn(role, workdir, quick, n_proc):
     with socket.socket() as s:
         s.bind(("localhost", 0))
         port = str(s.getsockname()[1])
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    from benchmarks.common import cpu_child_env
+    env = cpu_child_env()
+    env.pop("XLA_FLAGS", None)
     cmd = [sys.executable, "-m", "benchmarks.outofcore", "--child",
            role, "%d", port, workdir] + ([] if quick else ["--full"])
     procs = [subprocess.Popen([a if a != "%d" else str(i) for a in cmd],
@@ -231,6 +232,7 @@ def main(quick: bool = True) -> bool:
         stream = _spawn("stream", workdir, quick, N_PROC)
         inmem = _spawn("inmem", workdir, quick, N_PROC)
         dense = _spawn("dense", workdir, quick, 1)[0]
+        print(f"  ({common.CPU_REHEARSAL['note']})")
     finally:
         import shutil
         shutil.rmtree(workdir, ignore_errors=True)
@@ -283,6 +285,7 @@ def main(quick: bool = True) -> bool:
         f"({d_work / n:.2f})")
 
     report = {
+        "device": common.CPU_REHEARSAL,
         "quick": quick, "n": n, "d": DIM, "k": K,
         "chunk_rows": chunk_rows, "data_bytes": data_bytes,
         "rss_budget": budget, "dense_min": dense_min,

@@ -33,6 +33,9 @@ def main() -> int:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.util.env import enable_compile_cache
+    enable_compile_cache()
+
     # record the exact FitConfig of every fit the suites run, plus its
     # wall clock and a per-round obs summary (k-scans off the telemetry,
     # jit traces off the tracecount hooks scoped to this one fit)
@@ -89,9 +92,9 @@ def main() -> int:
                  "suite records its queue's high-water mark)"}
         if util is None:
             nulls["fit_roofline_utilization"] = (
-                "no trace_dir on this fit — the roofline gauge lives "
-                "in the obs metrics export (the kernels suite traces "
-                "every fit and records it per backend)")
+                "no trace_dir on this fit, or no published peaks for "
+                "its device_kind — the roofline gauge lives in the obs "
+                "metrics export, on devices in roofline.analysis.PEAKS")
         common.record_manifest(
             current["suite"], out.config.to_dict(),
             wall_s=round(wall, 3), obs=obs,

@@ -18,7 +18,6 @@ Artifact: artifacts/bench/xl_engine.json
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 from pathlib import Path
@@ -90,7 +89,9 @@ def child(quick: bool) -> None:
                   for out in runs.values()
                   for rec in out.telemetry if rec.val_mse is not None)
     target = 1.01 * emp_min
-    report = {"quick": quick, "n": n, "d": X.shape[1], "k": k,
+    from benchmarks.common import CPU_REHEARSAL
+    report = {"device": CPU_REHEARSAL,
+              "quick": quick, "n": n, "d": X.shape[1], "k": k,
               "mesh": list(mesh_shape), "empirical_min": emp_min}
     for name, out in runs.items():
         t, work, rounds = _cost_to_target(out.telemetry, target)
@@ -115,8 +116,7 @@ def child(quick: bool) -> None:
 def main(quick: bool = True) -> bool:
     from benchmarks import common
 
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
+    env = common.cpu_child_env()
     cmd = [sys.executable, "-m", "benchmarks.xl_engine", "--child"]
     if not quick:
         cmd.append("--full")
@@ -132,6 +132,7 @@ def main(quick: bool = True) -> bool:
         return common.check("xl-child", False,
                             "child timed out after 1800s")
     sys.stdout.write(r.stdout)
+    print(f"  ({common.CPU_REHEARSAL['note']})")
     if r.returncode != 0:
         sys.stderr.write(r.stderr)
         return common.check("xl-child", False, "child process failed")

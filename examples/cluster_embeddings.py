@@ -14,7 +14,9 @@ import numpy as np
 from repro import configs
 from repro.api import FitConfig, NestedKMeans
 from repro.models import model as M
+from repro.util.env import enable_compile_cache
 
+enable_compile_cache()
 cfg = configs.get_reduced("tinyllama-1.1b")
 params = M.init_params(jax.random.PRNGKey(0), cfg)
 E = np.asarray(params["embed"], np.float32)          # (vocab, d)

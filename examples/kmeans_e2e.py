@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from repro.api import CheckpointConfig, FitConfig, NestedKMeans
 from repro.core.state import full_mse
 from repro.data.synthetic import infmnist_like
+from repro.util.env import enable_compile_cache
 
 
 def main():
@@ -34,6 +35,7 @@ def main():
     ap.add_argument("--distributed", action="store_true")
     ap.add_argument("--n", type=int, default=20_000)
     args = ap.parse_args()
+    enable_compile_cache()
 
     X = infmnist_like(args.n + 2000, seed=0)
     X_train, X_val = X[: args.n], X[args.n:]

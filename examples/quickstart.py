@@ -14,7 +14,9 @@ import numpy as np
 
 from repro.api import FitConfig, NestedKMeans
 from repro.data.synthetic import infmnist_like
+from repro.util.env import enable_compile_cache
 
+enable_compile_cache()
 N, K = 20_000, 50
 X = infmnist_like(N + 2000, seed=0)
 X_train, X_val = X[:N], X[N:]

@@ -17,6 +17,7 @@ import numpy as np
 from repro.api import FitConfig, NestedKMeans
 from repro.data.synthetic import gaussian_blobs
 from repro.serve import ClusterService, IngestQueue
+from repro.util.env import enable_compile_cache
 
 K, DIM, CHUNK = 32, 16, 12          # CHUNK < K on purpose
 N_PER_PRODUCER = 4000
@@ -48,6 +49,7 @@ def consumer(svc: ClusterService, queries: np.ndarray, out: dict):
 
 
 def main():
+    enable_compile_cache()
     X, _ = gaussian_blobs(3 * N_PER_PRODUCER, k=K, dim=DIM, spread=5.0,
                           seed=0)
     parts = np.split(X, 3)

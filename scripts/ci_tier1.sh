@@ -60,7 +60,7 @@ if [ "$#" -eq 0 ]; then
     # and its round count must equal the loop's own schedule trace,
     # plus lint + hostsync staying green on the INSTRUMENTED loop).
     timeout 700 python -m pytest -x -q tests/test_obs.py
-    # the kernel dispatch plane (fast plan/parity/compat tests ran
+    # the kernel dispatch plane (fast plan/parity/tile-rule tests ran
     # above; this adds the slow-marked subprocess smoke: fused-round op
     # parity, pallas-vs-ref fit bit-parity on local tb/gb and XL
     # m=2/m=1, and retrace + hostsync green with the plan active).

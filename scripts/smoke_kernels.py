@@ -53,7 +53,7 @@ d_keep = rng.random(n).astype(np.float32)
 lb_keep = rng.random(n).astype(np.float32)
 valid = rng.random(n) < 0.9
 args = (x, c, a_prev, settled, d_keep, lb_keep, valid)
-outs_p = fused_nested_round_pallas(*args, bn=64, interpret=True)
+outs_p = fused_nested_round_pallas(*args, bn=128, interpret=True)
 outs_r = fused_nested_round_ref(*args)
 np.testing.assert_array_equal(np.asarray(outs_p[0]), np.asarray(outs_r[0]))
 for op, orf, name in zip(outs_p[1:], outs_r[1:],

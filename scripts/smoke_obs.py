@@ -52,8 +52,8 @@ def traced_fit(backend: str, trace_dir: str):
     engine = make_engine(config, mesh=_mesh_for(backend, config))
     run = engine.begin(X, config, X_val=X_val)
     obs = FitObserver(trace_dir, process_id=jax.process_index(),
-                      k=k, d=d, meta={"backend": backend,
-                                      "smoke": "obs"})
+                      k=k, d=d, device_kind=jax.devices()[0].device_kind,
+                      meta={"backend": backend, "smoke": "obs"})
     schedule = []
     try:
         out = run_loop(run, config, trace=schedule, obs=obs)

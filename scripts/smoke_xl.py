@@ -33,7 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import api
 from repro.core.distributed import (assign_top2_sharded, make_dp_round,
-                                    make_xl_round, shard_map_compat)
+                                    make_xl_round)
 from repro.core.state import full_mse
 from repro.kernels import ops, ref
 
@@ -89,9 +89,10 @@ def sharded_top2(x, C):
         off = jax.lax.axis_index("model") * Cl.shape[0]
         return assign_top2_sharded(xs, Cl, model_axis="model",
                                    k_offset=off)
-    sm = shard_map_compat(fn, mesh=mesh,
-                          in_specs=(P(None, None), P("model", None)),
-                          out_specs=(P(None), P(None), P(None)))
+    sm = jax.shard_map(fn, mesh=mesh,
+                       in_specs=(P(None, None), P("model", None)),
+                       out_specs=(P(None), P(None), P(None)),
+                       check_vma=False)
     return jax.jit(sm)(x, C)
 
 

@@ -102,15 +102,13 @@ def replicated_smap_update(mesh, axis: str = "data"):
     on every segment write."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.distributed import shard_map_compat
-
     def body(Xs, seg, at):
         upd = jax.lax.dynamic_update_slice(Xs, seg, (at, 0))
         return jax.lax.all_gather(upd, axis, axis=0, tiled=True)
 
-    fn = shard_map_compat(body, mesh=mesh,
-                          in_specs=(P(axis), P(axis), P()),
-                          out_specs=P())
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis), P(axis), P()),
+                       out_specs=P(), check_vma=False)
     return jax.jit(fn, donate_argnums=0)
 
 

@@ -224,8 +224,9 @@ def audit_backend(backend: str = "local", *, n: int = 2048, d: int = 8,
 
         from repro.obs import FitObserver
         obs = FitObserver(trace_dir, process_id=jax.process_index(),
-                          k=k, d=d, meta={"backend": backend,
-                                          "audit": "hostsync"})
+                          k=k, d=d,
+                          device_kind=jax.devices()[0].device_kind,
+                          meta={"backend": backend, "audit": "hostsync"})
     audit = HostSyncAudit(label=f"backend={backend}")
     try:
         with audit.installed():

@@ -133,6 +133,7 @@ class NestedKMeans:
                     cfg.trace_dir, process_id=jax.process_index(),
                     k=cfg.k, d=int(run.state.stats.C.shape[-1]),
                     bounds=cfg.bounds,
+                    device_kind=jax.devices()[0].device_kind,
                     meta={"backend": cfg.backend,
                           "algorithm": cfg.algorithm,
                           "bounds": cfg.bounds,

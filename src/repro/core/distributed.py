@@ -32,24 +32,6 @@ from repro.core.state import (ClusterStats, ElkanBounds, KMeansState,
 from repro.kernels import ops
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """`jax.shard_map` across jax versions (with replication checks off).
-
-    jax >= 0.6 exposes `jax.shard_map(..., check_vma=...)`; 0.4.x only
-    has `jax.experimental.shard_map.shard_map(..., check_rep=...)`.
-    """
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 # --------------------------------------------------------------------------
 # replicated-centroid engine (paper-scale k)
 # --------------------------------------------------------------------------
@@ -120,9 +102,9 @@ def make_sharded_round(mesh: Mesh, data_axes: Tuple[str, ...], *,
             use_shalf=use_shalf, plan=plan, data_axes=data_axes,
             n_valid=n_valid)
 
-    shardmapped = shard_map_compat(
+    shardmapped = jax.shard_map(
         fn, mesh=mesh, in_specs=(P(data_axes, None), state_specs),
-        out_specs=(state_specs, info_specs))
+        out_specs=(state_specs, info_specs), check_vma=False)
     return jax.jit(shardmapped)
 
 
@@ -300,8 +282,11 @@ def dp_round_body(x, C, *, data_axes: Tuple[str, ...],
     """
     if use_pallas:
         from repro.kernels.fused_round import fused_round_pallas
+        from repro.kernels.plan import resolve_plan
+        plan = resolve_plan("pallas", b=x.shape[0], k=C.shape[0],
+                            d=x.shape[1])
         a, d1, d2, S_loc, v_loc, sse_loc = fused_round_pallas(
-            x, C, interpret=jax.default_backend() != "tpu")
+            x, C, bn=plan.row_tile(x.shape[0]), interpret=plan.interpret)
     else:
         a, d1sq, _ = ops.assign_top2(x, C)
         d1 = d1sq
@@ -332,11 +317,11 @@ def make_dp_round(mesh: Mesh, *, rho: float = float("inf"),
     axes = tuple(mesh.axis_names)
     fn = functools.partial(dp_round_body, data_axes=axes, rho=rho,
                            use_pallas=use_pallas)
-    sm = shard_map_compat(
+    sm = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(axes, None), P(None, None)),
         out_specs=(P(None, None), P(None, None), P(None),
-                   P(axes), P(axes), P(), P(), P()))
+                   P(axes), P(axes), P(), P(), P()), check_vma=False)
     return jax.jit(sm)
 
 
@@ -358,10 +343,10 @@ def make_xl_round(mesh: Mesh, *, k: int,
 
     fn = functools.partial(xl_round_body, k=k, data_axes=data_axes,
                            model_axis=model_axis, rho=rho)
-    sm = shard_map_compat(
+    sm = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(data_axes, None), P(model_axis, None),
                   P(model_axis, None), kshard),
         out_specs=(P(model_axis, None), P(model_axis, None), kshard,
-                   row, row, row, P(), P(), P()))
+                   row, row, row, P(), P(), P()), check_vma=False)
     return jax.jit(sm)

@@ -49,7 +49,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import controller, rounds
 from repro.core.distributed import (_fold_top2, assign_top2_sharded,
-                                    per_shard_n_valid, shard_map_compat)
+                                    per_shard_n_valid)
 from repro.core.rounds import _euclid
 from repro.core.state import (ClusterStats, ElkanBounds, KMeansState,
                               PointState, RoundInfo, centroid_update)
@@ -585,9 +585,9 @@ def make_xl_nested_round(mesh: Mesh, data_axes: Tuple[str, ...], *,
             data_axes=data_axes, model_axis=model_axis, capacity=capacity,
             use_shalf=use_shalf, plan=plan, n_valid=n_valid)
 
-    shardmapped = shard_map_compat(
+    shardmapped = jax.shard_map(
         fn, mesh=mesh, in_specs=(P(data_axes, None), state_specs),
-        out_specs=(state_specs, info_specs))
+        out_specs=(state_specs, info_specs), check_vma=False)
     return jax.jit(shardmapped)
 
 

@@ -1,24 +1,26 @@
-"""Fused k-means round kernel: assign + cluster-sum in ONE pass over X.
+"""Fused nested k-means round: assign + Hamerly keep-select + delta-S/v
++ sse in ONE pass over X.
 
 The paper's assignment step followed by the S/v/sse accumulation reads X
-twice when expressed as separate ops (and XLA-CPU materialises another
-3-5 staged intermediates — measured 1.8 TB vs the 0.27 TB single-pass
-floor on kmeans_xl; EXPERIMENTS.md §Perf). On TPU the whole round fits a
-single Pallas kernel:
+twice when expressed as separate ops. On TPU the whole round is a single
+Pallas kernel:
 
-  * the full centroid block C (k, d) stays VMEM-resident (k=4096, d=1024
-    bf16 = 8 MiB against ~128 MiB VMEM),
+  * the full centroid block C (kp, d) stays VMEM-resident,
   * grid over point tiles (sequential): each (bn, d) X tile is read from
-    HBM exactly once; the MXU computes scores = X·Cᵀ; the VPU folds
-    top-2 (argmin via one-hot max trick) and accumulates
-        S += onehotᵀ·X       (MXU)
-        v += Σ onehot, sse += Σ d²
-    into revisited (k, d)/(k,) output blocks that never leave VMEM.
+    HBM exactly once; the MXU computes the (kp, bn) distance block; the
+    VPU folds top-2 over the centroid axis and accumulates
+        S += coeff·X        (MXU)
+        v += Σ coeff, sse += Σ d²
+    into revisited (kp, d) / (kp, 1) output blocks that never leave VMEM.
+
+Layout: centroids on sublanes, rows on lanes. The distance block is
+(kp, bn), so every per-row quantity — the assignment, distances,
+bounds and masks — is a lane-dense (1, bn) row, and the per-row vectors
+travel in HBM as (1, n) arrays. Rank-1 (bn,) blocks do not compile
+(XLA and Mosaic disagree on their tiling), and a (bn, 1) column would
+pad every row to 128 lanes.
 
 HBM traffic per round = |X| + |C| + |outputs| — the optimal single pass.
-Distance identities: ||x-c||² = ||x||² - 2x·c + ||c||²; the scores matrix
-only needs (-2x·c + ||c||²) for the argmin, ||x||² is added back on the
-winning value only.
 """
 from __future__ import annotations
 
@@ -27,124 +29,52 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.plan import check_tile, vmem_limit_bytes
+
+HIGHEST = jax.lax.Precision.HIGHEST
+#: contract the last dim of both operands: (m, d) x (n, d) -> (m, n)
+NT = (((1,), (1,)), ((), ()))
+#: plain matmul: (m, n) x (n, d) -> (m, d)
+NN = (((1,), (0,)), ((), ()))
 
 
-def _round_kernel(x_ref, c_ref, cn_ref, a_ref, d1_ref, d2_ref, s_ref,
-                  v_ref, sse_ref, *, k: int):
-    n_idx = pl.program_id(0)
+def row_norms(x: jax.Array) -> jax.Array:
+    """Squared norms of the rows of ``x`` (bn, d) as a lane-dense (1, bn)
+    row: a contraction against a ones row keeps rows on lanes, where a
+    sum over d would leave them on sublanes."""
+    ones = jnp.ones((1, x.shape[1]), jnp.float32)
+    return jax.lax.dot_general(ones, x * x, NT, precision=HIGHEST,
+                               preferred_element_type=jnp.float32)
 
-    x = x_ref[...].astype(jnp.float32)            # (bn, d)
-    c = c_ref[...].astype(jnp.float32)            # (k, d) VMEM-resident
-    cn = cn_ref[...].astype(jnp.float32)          # (k,)
 
-    xn = jnp.sum(x * x, axis=1, keepdims=True)    # (bn, 1)
-    dot = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
+def dist2_block(x: jax.Array, c: jax.Array, cn: jax.Array) -> jax.Array:
+    """(kp, bn) squared distances between centroids ``c`` (kp, d) with
+    column norms ``cn`` (kp, 1) and rows ``x`` (bn, d): the `ref`
+    expression ``max(|x|² - 2x·c + |c|², 0)``, so labels match it."""
+    dot = jax.lax.dot_general(c, x, NT, precision=HIGHEST,
                               preferred_element_type=jnp.float32)
-    # partial distance (no xn): argmin-equivalent, cheaper to fold
-    pd = cn[None, :] - 2.0 * dot                  # (bn, k)
-
-    b1 = jnp.min(pd, axis=1)
-    a = jnp.argmin(pd, axis=1).astype(jnp.int32)
-    cols = jax.lax.broadcasted_iota(jnp.int32, pd.shape, 1)
-    b2 = jnp.min(jnp.where(cols == a[:, None], jnp.inf, pd), axis=1)
-
-    d1 = jnp.maximum(b1 + xn[:, 0], 0.0)          # true squared distances
-    d2 = jnp.maximum(b2 + xn[:, 0], 0.0)
-
-    a_ref[...] = a
-    d1_ref[...] = d1
-    d2_ref[...] = d2
-
-    onehot = (cols == a[:, None]).astype(jnp.float32)     # (bn, k)
-    s_part = jax.lax.dot_general(onehot, x, (((0,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-    v_part = jnp.sum(onehot, axis=0)
-    sse_part = jnp.sum(onehot * d1[:, None], axis=0)
-
-    @pl.when(n_idx == 0)
-    def _init():
-        s_ref[...] = s_part
-        v_ref[...] = v_part
-        sse_ref[...] = sse_part
-
-    @pl.when(n_idx != 0)
-    def _acc():
-        s_ref[...] += s_part
-        v_ref[...] += v_part
-        sse_ref[...] += sse_part
+    return jnp.maximum(row_norms(x) - 2.0 * dot + cn, 0.0)
 
 
-@functools.partial(jax.jit, static_argnames=("bn", "interpret"))
-def fused_round_pallas(x: jax.Array, c: jax.Array, *, bn: int = 256,
-                       interpret: bool = False):
-    """One fused assignment+accumulation pass.
+def top2(d2m: jax.Array):
+    """(idx, min, 2nd-min) over the centroid axis 0 of ``d2m`` (kp, bn),
+    each (1, bn). Ties go to the lowest index, as `jnp.argmin`."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, d2m.shape, 0)
+    b1 = jnp.min(d2m, axis=0, keepdims=True)
+    idx = jnp.min(jnp.where(d2m == b1, rows, d2m.shape[0]), axis=0,
+                  keepdims=True)
+    b2 = jnp.min(jnp.where(rows == idx, jnp.inf, d2m), axis=0,
+                 keepdims=True)
+    return idx, b1, b2, rows
 
-    x: (n, d), c: (k, d). Returns (a, d1_sq, d2_sq, S, v, sse) where S/v/
-    sse are the per-cluster sums/counts/sse of THIS pass. n padded to bn;
-    padded rows are masked out of the accumulators by the wrapper.
-    """
-    n, d = x.shape
-    k = c.shape[0]
-    n_pad = -n % bn
-    cn = jnp.sum(c.astype(jnp.float32) ** 2, axis=1)
+
+def to_row(v: jax.Array, n_pad: int, fill) -> jax.Array:
+    """(n,) vector -> lane-dense (1, n + n_pad) row, padded with ``fill``."""
     if n_pad:
-        x = jnp.pad(x, ((0, n_pad), (0, 0)))
-    np_ = x.shape[0]
-
-    kernel = functools.partial(_round_kernel, k=k)
-    a, d1, d2, S, v, sse = pl.pallas_call(
-        kernel,
-        grid=(np_ // bn,),
-        in_specs=[
-            pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((k, d), lambda i: (0, 0)),
-            pl.BlockSpec((k,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((k, d), lambda i: (0, 0)),
-            pl.BlockSpec((k,), lambda i: (0,)),
-            pl.BlockSpec((k,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((np_,), jnp.int32),
-            jax.ShapeDtypeStruct((np_,), jnp.float32),
-            jax.ShapeDtypeStruct((np_,), jnp.float32),
-            jax.ShapeDtypeStruct((k, d), jnp.float32),
-            jax.ShapeDtypeStruct((k,), jnp.float32),
-            jax.ShapeDtypeStruct((k,), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(x, c, cn)
-    if n_pad:
-        # padded rows were assigned to argmin over real centroids; remove
-        # their contributions (they are all-zero rows: d1 = ||c_a||^2)
-        pad_a = a[n:]
-        pad_d1 = d1[n:]
-        S = S.at[pad_a].add(-jnp.zeros((n_pad, d), jnp.float32))
-        v = v.at[pad_a].add(-1.0)
-        sse = sse.at[pad_a].add(-pad_d1)
-    return a[:n], d1[:n], d2[:n], S, v, sse
-
-
-def fused_round_ref(x: jax.Array, c: jax.Array):
-    """Pure-jnp oracle for the fused round."""
-    from repro.kernels import ref
-
-    d2m = ref.pairwise_dist2(x, c)
-    a = jnp.argmin(d2m, axis=1).astype(jnp.int32)
-    d1 = jnp.min(d2m, axis=1)
-    k = c.shape[0]
-    cols = jnp.arange(k)[None, :]
-    d2nd = jnp.min(jnp.where(cols == a[:, None], jnp.inf, d2m), axis=1)
-    S, v = ref.cluster_sum_ref(x, a, k)
-    sse = jax.ops.segment_sum(d1, a, num_segments=k)
-    return a, d1, d2nd, S, v, sse
+        v = jnp.pad(v, (0, n_pad), constant_values=fill)
+    return v.reshape(1, -1)
 
 
 def _nested_kernel(x_ref, c_ref, cn_ref, ap_ref, keep_ref, dk_ref,
@@ -156,31 +86,19 @@ def _nested_kernel(x_ref, c_ref, cn_ref, ap_ref, keep_ref, dk_ref,
     the kernel only executes them, so the growth/bound schedule is
     identical between backends by construction. For kept rows the
     retained distance/bound (dk/lbk) pass straight through; everyone
-    still pays the scores matmul because the dense nested path refreshes
-    the second-closest bound for all rows each round.
+    still pays the distance matmul because the dense nested path
+    refreshes the second-closest bound for all rows each round.
     """
     n_idx = pl.program_id(0)
 
     x = x_ref[...].astype(jnp.float32)            # (bn, d)
-    c = c_ref[...].astype(jnp.float32)            # (kp, d) VMEM-resident
-    cn = cn_ref[...].astype(jnp.float32)          # (kp,) +inf on pads
-    ap = ap_ref[...]                              # (bn,) prev assignment
+    ap = ap_ref[...]                              # (1, bn) prev assignment
     keep = keep_ref[...] != 0                     # settled: keep a_prev
     vm = vm_ref[...] != 0                         # valid (un-padded) rows
 
-    # Full squared distances — the REF expression (xn - 2x·c + cn,
-    # clamped), not the partial-distance trick of `_round_kernel`: label
-    # parity with the ref round path is the contract here, and the two
-    # expressions round differently at ties.
-    xn = jnp.sum(x * x, axis=1, keepdims=True)
-    dot = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    d2m = jnp.maximum(xn - 2.0 * dot + cn[None, :], 0.0)
-
-    af = jnp.argmin(d2m, axis=1).astype(jnp.int32)
-    b1 = jnp.min(d2m, axis=1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, d2m.shape, 1)
-    b2 = jnp.min(jnp.where(cols == af[:, None], jnp.inf, d2m), axis=1)
+    # +inf norms on pad centroids: never the argmin
+    d2m = dist2_block(x, c_ref[...], cn_ref[...])   # (kp, bn)
+    af, b1, b2, rows = top2(d2m)
     d1 = jnp.sqrt(b1)
     d2 = jnp.sqrt(b2)
 
@@ -194,20 +112,18 @@ def _nested_kernel(x_ref, c_ref, cn_ref, ap_ref, keep_ref, dk_ref,
     # delta-S/v for already-seen points (rounds._delta_sv semantics),
     # folded into ONE matmul via a signed coefficient matrix: +1 at the
     # new cluster for joins, -1 at the old cluster for leaves. Masked
-    # rows (a_new == -1) and grid pads carry zero coefficients, so no
-    # post-hoc pad correction is needed.
+    # rows (a_new == -1) and grid pads carry zero coefficients.
     seen = ap >= 0
     changed = seen & (a_new != ap)
     w_rm = jnp.where(changed, 1.0, 0.0)
     w_add = jnp.where((changed | ~seen) & (a_new >= 0), 1.0, 0.0)
-    add_oh = (cols == jnp.clip(a_new, 0, k - 1)[:, None]).astype(
-        jnp.float32)
-    rm_oh = (cols == jnp.clip(ap, 0, k - 1)[:, None]).astype(jnp.float32)
-    coeff = w_add[:, None] * add_oh - w_rm[:, None] * rm_oh   # (bn, kp)
-    s_part = jax.lax.dot_general(coeff, x, (((0,), (0,)), ((), ())),
+    add_oh = (rows == jnp.clip(a_new, 0, k - 1)).astype(jnp.float32)
+    rm_oh = (rows == jnp.clip(ap, 0, k - 1)).astype(jnp.float32)
+    coeff = w_add * add_oh - w_rm * rm_oh                     # (kp, bn)
+    s_part = jax.lax.dot_general(coeff, x, NN, precision=HIGHEST,
                                  preferred_element_type=jnp.float32)
-    v_part = jnp.sum(coeff, axis=0)
-    sse_part = jnp.sum(add_oh * (d_new * d_new)[:, None], axis=0)
+    v_part = jnp.sum(coeff, axis=1, keepdims=True)            # (kp, 1)
+    sse_part = jnp.sum(add_oh * (d_new * d_new), axis=1, keepdims=True)
 
     @pl.when(n_idx == 0)
     def _init():
@@ -240,7 +156,9 @@ def fused_nested_round_pallas(x: jax.Array, c: jax.Array,
     (-1 on invalid rows), euclidean distance to the assigned centroid,
     the refreshed second-closest lower bound, the signed delta cluster
     sums/counts for seen points, and per-cluster sse of active members.
+    ``bn`` must be a TPU row tile (`plan.check_tile`).
     """
+    check_tile("fused_nested_round_pallas", bn=bn)
     n, d = x.shape
     k = c.shape[0]
     kp = k + (-k % 128)
@@ -250,54 +168,43 @@ def fused_nested_round_pallas(x: jax.Array, c: jax.Array,
         cf = jnp.pad(cf, ((0, kp - k), (0, 0)))
         cn = jnp.pad(cn, (0, kp - k), constant_values=jnp.inf)
     n_pad = -n % bn
-    settled = settled.astype(jnp.int32)
-    valid = valid.astype(jnp.int32)
     if n_pad:
-        # pad rows: a_prev=-1 (unseen) + valid=0 ⇒ every coefficient and
-        # sse term is zero; outputs are sliced off below.
         x = jnp.pad(x, ((0, n_pad), (0, 0)))
-        a_prev = jnp.pad(a_prev, (0, n_pad), constant_values=-1)
-        settled = jnp.pad(settled, (0, n_pad), constant_values=1)
-        d_keep = jnp.pad(d_keep, (0, n_pad))
-        lb_keep = jnp.pad(lb_keep, (0, n_pad))
-        valid = jnp.pad(valid, (0, n_pad))
     np_ = x.shape[0]
+    # pad rows: a_prev=-1 (unseen) + valid=0 ⇒ every coefficient and
+    # sse term is zero; outputs are sliced off below.
+    rows_in = (to_row(a_prev, n_pad, -1),
+               to_row(settled.astype(jnp.int32), n_pad, 1),
+               to_row(d_keep, n_pad, 0.0), to_row(lb_keep, n_pad, 0.0),
+               to_row(valid.astype(jnp.int32), n_pad, 0))
 
-    kernel = functools.partial(_nested_kernel, k=k)
+    row = pl.BlockSpec((1, bn), lambda i: (0, i))
+    full = pl.BlockSpec((kp, d), lambda i: (0, 0))
+    col = pl.BlockSpec((kp, 1), lambda i: (0, 0))
+    vmem = vmem_limit_bytes(
+        "fused_nested_round_pallas",
+        blocks=[(bn, d), (kp, d), (kp, d), (kp, 1), (kp, 1), (kp, 1)]
+        + [(1, bn)] * 8,
+        temps=[(kp, bn)] * 8 + [(kp, d)])
     a, dn, lb, S, v, sse = pl.pallas_call(
-        kernel,
+        functools.partial(_nested_kernel, k=k),
         grid=(np_ // bn,),
-        in_specs=[
-            pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((kp, d), lambda i: (0, 0)),
-            pl.BlockSpec((kp,), lambda i: (0,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((kp, d), lambda i: (0, 0)),
-            pl.BlockSpec((kp,), lambda i: (0,)),
-            pl.BlockSpec((kp,), lambda i: (0,)),
-        ],
+        in_specs=[pl.BlockSpec((bn, d), lambda i: (i, 0)), full, col]
+        + [row] * 5,
+        out_specs=[row, row, row, full, col, col],
         out_shape=[
-            jax.ShapeDtypeStruct((np_,), jnp.int32),
-            jax.ShapeDtypeStruct((np_,), jnp.float32),
-            jax.ShapeDtypeStruct((np_,), jnp.float32),
+            jax.ShapeDtypeStruct((1, np_), jnp.int32),
+            jax.ShapeDtypeStruct((1, np_), jnp.float32),
+            jax.ShapeDtypeStruct((1, np_), jnp.float32),
             jax.ShapeDtypeStruct((kp, d), jnp.float32),
-            jax.ShapeDtypeStruct((kp,), jnp.float32),
-            jax.ShapeDtypeStruct((kp,), jnp.float32),
+            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((kp, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=vmem),
         interpret=interpret,
-    )(x, cf, cn, a_prev, settled, d_keep, lb_keep, valid)
-    return a[:n], dn[:n], lb[:n], S[:k], v[:k], sse[:k]
+    )(x, cf, cn[:, None], *rows_in)
+    return (a[0, :n], dn[0, :n], lb[0, :n], S[:k], v[:k, 0], sse[:k, 0])
 
 
 def fused_nested_round_ref(x: jax.Array, c: jax.Array, a_prev: jax.Array,
@@ -328,3 +235,34 @@ def fused_nested_round_ref(x: jax.Array, c: jax.Array, a_prev: jax.Array,
     sse = jax.ops.segment_sum(d_new * d_new, jnp.clip(a_new, 0, k - 1),
                               num_segments=k)
     return (a_new, d_new, lb_new, S_add - S_rm, v_add - v_rm, sse)
+
+
+def fused_round_pallas(x: jax.Array, c: jax.Array, *, bn: int = 256,
+                       interpret: bool = False):
+    """One fused assignment+accumulation pass over fresh rows.
+
+    x: (n, d), c: (k, d). Returns (a, d1_sq, d2_sq, S, v, sse) where
+    S/v/sse are the per-cluster sums/counts/sse of THIS pass: the nested
+    kernel with every row unseen and valid, so all rows join.
+    """
+    n = x.shape[0]
+    a, d1, d2, S, v, sse = fused_nested_round_pallas(
+        x, c, jnp.full((n,), -1, jnp.int32), jnp.zeros((n,), jnp.bool_),
+        jnp.zeros((n,), jnp.float32), jnp.zeros((n,), jnp.float32),
+        jnp.ones((n,), jnp.bool_), bn=bn, interpret=interpret)
+    return a, d1 * d1, d2 * d2, S, v, sse
+
+
+def fused_round_ref(x: jax.Array, c: jax.Array):
+    """Pure-jnp oracle for the fused round."""
+    from repro.kernels import ref
+
+    d2m = ref.pairwise_dist2(x, c)
+    a = jnp.argmin(d2m, axis=1).astype(jnp.int32)
+    d1 = jnp.min(d2m, axis=1)
+    k = c.shape[0]
+    cols = jnp.arange(k)[None, :]
+    d2nd = jnp.min(jnp.where(cols == a[:, None], jnp.inf, d2m), axis=1)
+    S, v = ref.cluster_sum_ref(x, a, k)
+    sse = jax.ops.segment_sum(d1, a, num_segments=k)
+    return a, d1, d2nd, S, v, sse

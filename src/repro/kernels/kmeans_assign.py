@@ -1,17 +1,17 @@
 """Fused pairwise-distance + top-2 argmin Pallas TPU kernel.
 
-The k-means assignment hot spot. For a tile of points the MXU computes the
-``x @ c.T`` Gram block while the VPU fuses the ``|x|^2 - 2 x.c + |c|^2``
-expansion and a running (min, 2nd-min, argmin) reduction carried across the
-centroid grid dimension in the (revisited) output blocks.
+The k-means assignment hot spot. For a tile of points the MXU computes
+the (bk, bn) Gram block of a centroid tile against a row tile while the
+VPU fuses the ``|x|^2 - 2 x.c + |c|^2`` expansion and a running (min,
+2nd-min, argmin) reduction carried across the centroid grid dimension in
+the (revisited) output blocks.
 
-Grid: (n_blocks, k_blocks) with the k dimension sequential ("arbitrary") so
-output blocks act as accumulators; the point dimension is parallel.
+Grid: (n_blocks, k_blocks) with the k dimension sequential ("arbitrary")
+so output blocks act as accumulators; the point dimension is parallel.
 
-BlockSpecs keep an (bn, d) X tile and a (bk, d) centroid tile resident in
-VMEM; bn/bk default to MXU-aligned 256/128. d is kept whole per tile —
-k-means dims (784/1024/2048) fit comfortably: a 256x2048 f32 tile is 2 MiB
-against ~16 MiB VMEM.
+Layout as in `fused_round`: centroids on sublanes, rows on lanes, so the
+per-row outputs are lane-dense (1, bn) blocks of (1, n) arrays. d is kept
+whole per tile; the plan bounds the (bn, d) X tile (`plan.tile_fits`).
 
 Padded centroids carry +inf norms so they can never win the argmin; padded
 points produce garbage rows that the wrapper slices off.
@@ -23,10 +23,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
-_NEG_BIG = float("inf")   # python literal: pallas kernels may not capture
-                          # traced constants
+from repro.kernels.fused_round import dist2_block, top2
+from repro.kernels.plan import check_tile, vmem_limit_bytes
 
 
 def _assign_kernel(x_ref, c_ref, cn_ref, a_ref, d1_ref, d2_ref, *, bk: int):
@@ -34,22 +34,9 @@ def _assign_kernel(x_ref, c_ref, cn_ref, a_ref, d1_ref, d2_ref, *, bk: int):
     k_idx = pl.program_id(1)
 
     x = x_ref[...].astype(jnp.float32)             # (bn, d)
-    c = c_ref[...].astype(jnp.float32)             # (bk, d)
-    cn = cn_ref[...].astype(jnp.float32)           # (bk,)
-
-    xn = jnp.sum(x * x, axis=1, keepdims=True)     # (bn, 1)
-    dot = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)        # (bn, bk) on the MXU
-    d2 = jnp.maximum(xn - 2.0 * dot + cn[None, :], 0.0)
-    # padded centroids have cn = +inf -> d2 = +inf, never selected
-
-    # top-2 within this centroid tile
-    b1 = jnp.min(d2, axis=1)                                    # (bn,)
-    bi = jnp.argmin(d2, axis=1).astype(jnp.int32) + k_idx * bk  # global idx
-    col = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1) + k_idx * bk
-    d2_wo_min = jnp.where(col == bi[:, None], _NEG_BIG, d2)
-    b2 = jnp.min(d2_wo_min, axis=1)
+    d2 = dist2_block(x, c_ref[...].astype(jnp.float32), cn_ref[...])
+    bi, b1, b2, _ = top2(d2)                       # each (1, bn)
+    bi = bi + k_idx * bk                           # global index
 
     @pl.when(k_idx == 0)
     def _init():
@@ -76,8 +63,10 @@ def assign_top2_pallas(x: jax.Array, c: jax.Array, *, bn: int = 256,
     """(a, d1, d2) = fused nearest/2nd-nearest centroid search.
 
     x: (n, d); c: (k, d). Returns int32 (n,), f32 (n,), f32 (n,) with
-    SQUARED distances. n is padded to bn, k to bk internally.
+    SQUARED distances. n is padded to bn, k to bk internally; both must
+    be TPU tiles (`plan.check_tile`).
     """
+    check_tile("assign_top2_pallas", bn=bn, bk=bk)
     n, d = x.shape
     k = c.shape[0]
     n_pad = -n % bn
@@ -91,28 +80,28 @@ def assign_top2_pallas(x: jax.Array, c: jax.Array, *, bn: int = 256,
         x = jnp.pad(x, ((0, n_pad), (0, 0)))
     np_, kp = x.shape[0], c.shape[0]
 
-    grid = (np_ // bn, kp // bk)
-    kernel = functools.partial(_assign_kernel, bk=bk)
+    row = pl.BlockSpec((1, bn), lambda i, j: (0, i))
+    vmem = vmem_limit_bytes(
+        "assign_top2_pallas",
+        blocks=[(bn, d), (bk, d), (bk, 1)] + [(1, bn)] * 3,
+        temps=[(bk, bn)] * 6)
     a, d1, d2 = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_assign_kernel, bk=bk),
+        grid=(np_ // bn, kp // bk),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bk, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bk,), lambda i, j: (j,)),
+            pl.BlockSpec((bk, 1), lambda i, j: (j, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-        ],
+        out_specs=[row, row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((np_,), jnp.int32),
-            jax.ShapeDtypeStruct((np_,), jnp.float32),
-            jax.ShapeDtypeStruct((np_,), jnp.float32),
+            jax.ShapeDtypeStruct((1, np_), jnp.int32),
+            jax.ShapeDtypeStruct((1, np_), jnp.float32),
+            jax.ShapeDtypeStruct((1, np_), jnp.float32),
         ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem),
         interpret=interpret,
-    )(x, c, cn)
-    return a[:n], d1[:n], d2[:n]
+    )(x, c, cn[:, None])
+    return a[0, :n], d1[0, :n], d2[0, :n]

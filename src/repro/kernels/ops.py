@@ -5,10 +5,10 @@ down; every op here takes ``plan=`` and launches accordingly. Legacy
 callers that still hold a backend STRING (serve snapshots,
 `NestedKMeans.predict`) pass ``backend=`` instead and get a per-bucket
 cached plan resolved on the spot — same dispatch rules, no second code
-path. On non-TPU platforms (this container) pallas runs in interpret
-mode so the kernel bodies execute exactly as written; on TPU they
-compile to Mosaic. ``"ref"`` routes to the pure-jnp oracle — the fast
-path on CPU and the semantic baseline everywhere.
+path. On the CPU pallas runs in interpret mode so the kernel bodies
+execute exactly as written; on TPU they compile to Mosaic. ``"ref"``
+routes to the pure-jnp oracle — the fast path on CPU and the semantic
+baseline everywhere.
 """
 from __future__ import annotations
 
@@ -19,11 +19,7 @@ from repro.kernels.cluster_sum import cluster_sum_pallas
 from repro.kernels.fused_round import (fused_nested_round_pallas,
                                        fused_nested_round_ref)
 from repro.kernels.kmeans_assign import assign_top2_pallas
-from repro.kernels.plan import KernelPlan, next_pow2, resolve_plan
-
-
-def _pad128(k: int) -> int:
-    return k + (-k % 128)
+from repro.kernels.plan import LANE, KernelPlan, resolve_plan
 
 
 def _plan_for(plan: KernelPlan | None, backend: str | None, n: int,
@@ -35,12 +31,6 @@ def _plan_for(plan: KernelPlan | None, backend: str | None, n: int,
     return resolve_plan(backend, b=n, k=k, d=d)
 
 
-def _clamp_bn(bn: int, n: int) -> int:
-    """Row tile no larger than the (pow2-padded) batch: a plan tuned at
-    b_max still launches sane grids for the small early nested rounds."""
-    return max(8, min(bn, next_pow2(n)))
-
-
 def assign_top2(x: jax.Array, c: jax.Array, *,
                 plan: KernelPlan | None = None,
                 backend: str | None = None):
@@ -49,8 +39,8 @@ def assign_top2(x: jax.Array, c: jax.Array, *,
     p = _plan_for(plan, backend, n, k, x.shape[1])
     if p.backend == "ref":
         return ref.assign_top2_ref(x, c)
-    return assign_top2_pallas(x, c, bn=_clamp_bn(p.bn, n),
-                              bk=min(p.bk, _pad128(k)),
+    return assign_top2_pallas(x, c, bn=p.row_tile(n),
+                              bk=min(p.bk, k + (-k % LANE)),
                               interpret=p.interpret)
 
 
@@ -62,10 +52,9 @@ def cluster_sum(x: jax.Array, a: jax.Array, k: int, *,
     p = _plan_for(plan, backend, x.shape[0], k, x.shape[1])
     if p.backend == "ref":
         return ref.cluster_sum_ref(x, a, k, weights=weights)
-    s, v = cluster_sum_pallas(x, a, _pad128(k), weights=weights,
-                              bn=_clamp_bn(p.bn, x.shape[0]), bd=p.bd,
+    return cluster_sum_pallas(x, a, k, weights=weights,
+                              bn=p.row_tile(x.shape[0]), bd=p.bd,
                               interpret=p.interpret)
-    return s[:k], v[:k]
 
 
 def fused_nested_round(x: jax.Array, c: jax.Array, a_prev: jax.Array,
@@ -86,5 +75,5 @@ def fused_nested_round(x: jax.Array, c: jax.Array, a_prev: jax.Array,
                                       lb_keep, valid)
     return fused_nested_round_pallas(x, c, a_prev, settled, d_keep,
                                      lb_keep, valid,
-                                     bn=_clamp_bn(p.bn, n),
+                                     bn=p.row_tile(n),
                                      interpret=p.interpret)

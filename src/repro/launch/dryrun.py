@@ -37,6 +37,8 @@ from repro.roofline import hlo_cost
 from repro.train import step as tstep
 
 ARTIFACTS = Path(__file__).resolve().parents[3] / "artifacts" / "dryrun"
+#: the dry-run's production mesh is a pod of v5e chips
+_PEAKS = ra.PEAKS[ra.V5E]
 
 
 def _mesh_tag(multi_pod: bool) -> str:
@@ -166,7 +168,7 @@ def run_cell(arch: str, shape: ShapeConfig, *, multi_pod: bool,
         coll = ra.parse_collectives(hlo)   # static (per-occurrence) view
         flops, hbm = hc.flops, hc.bytes
         n_chips = len(jax.devices())
-        roof = ra.roofline_terms(flops, hbm, hc.wire,
+        roof = ra.roofline_terms(flops, hbm, hc.wire, peaks=_PEAKS,
                                  model_flops=model_flops / n_chips)
         rec.update({
             "ok": True,
@@ -268,12 +270,12 @@ def run_kmeans_cell(name: str, *, multi_pod: bool,
         n_chips = len(jax.devices())
         b_glob = N if kcfg.shard_centroids else b_local * n_dp
         model_flops = 2.0 * b_glob * d * k / n_chips
-        roof = ra.roofline_terms(flops, hbm, hc.wire,
+        roof = ra.roofline_terms(flops, hbm, hc.wire, peaks=_PEAKS,
                                  model_flops=model_flops)
         if "pallas_analytic" in rec:
             pa = rec["pallas_analytic"]
             pr = ra.roofline_terms(pa["flops"], pa["hbm_bytes"], hc.wire,
-                                   model_flops=model_flops)
+                                   peaks=_PEAKS, model_flops=model_flops)
             pa["roofline"] = {
                 "compute_s": pr.compute_s, "memory_s": pr.memory_s,
                 "collective_s": pr.collective_s,

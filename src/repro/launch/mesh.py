@@ -18,13 +18,9 @@ from repro.util.env import device_count_flag, require_devices  # noqa: F401
 
 
 def _make_mesh(shape, axes):
-    """`jax.make_mesh` with Auto axis types where the jax version has
-    them (>= 0.5); plain mesh on 0.4.x, which lacks AxisType."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis of type Auto."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -25,12 +25,11 @@ From ``(k, d)`` the costs are
                  annulus is small.
 
 `WorkModel` prices a round with ``roofline/analysis.roofline_terms``
-(TPU v5e peak model) and turns the measured wall time into a
-**utilization** fraction — achieved / attainable, given the round's own
-arithmetic intensity. This is the live gauge the ROADMAP's "as fast as
-the hardware allows" north star is measured by: a CPU fit reads a few
-percent; the Pallas hot-path PR is expected to move it, and now has an
-in-tree number to move.
+against the peaks of the fit's own chip (``device_kind``) and turns the
+measured wall time into a **utilization** fraction — achieved /
+attainable, given the round's own arithmetic intensity. A device with no
+published peaks (the CPU among them) gets no roofline and no
+utilization: `WorkModel.no_roofline` says why.
 
 Plain Python + the jax-free roofline module — safe to import anywhere,
 including inside the transfer-guarded host loop.
@@ -40,7 +39,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-from repro.roofline.analysis import Roofline, roofline_terms
+from repro.roofline.analysis import Roofline, peaks_for, roofline_terms
 
 #: FLOPs per (point, centroid, dim): diff, square (fused mul-add), and
 #: the running-min compare amortised across dims.
@@ -67,8 +66,9 @@ class RoundWork:
     dist_evals: int        # (point, centroid) pair distance evals
     flops: float
     hbm_bytes: float
-    bound_s: float         # roofline lower bound for this much work
-    bottleneck: str        # "compute" | "memory" | "collective"
+    bound_s: Optional[float]     # roofline lower bound for this work;
+                                 # None without the chip's peaks
+    bottleneck: Optional[str]    # "compute" | "memory" | "collective"
     dt_s: Optional[float] = None
     utilization: Optional[float] = None   # bound_s / dt_s, in [0, ~1]
     unit: str = "kscan"    # what n_recomputed counted ("kscan" | "pair")
@@ -80,10 +80,13 @@ class WorkModel:
     ``unit`` declares what the rounds' ``n_recomputed`` counts:
     "kscan" (none/hamerly2 — points times full k) or "pair"
     (elkan/exponion — individual pair distances). Use `for_bounds` to
-    pick the unit from a fit's bound family.
+    pick the unit from a fit's bound family. ``device_kind`` picks the
+    peaks the roofline uses; without published peaks for it, rounds are
+    priced in operations and bytes only.
     """
 
-    def __init__(self, k: int, d: int, unit: str = "kscan"):
+    def __init__(self, k: int, d: int, unit: str = "kscan", *,
+                 device_kind: Optional[str] = None):
         if k < 1 or d < 1:
             raise ValueError(f"WorkModel needs k, d >= 1, got k={k} d={d}")
         if unit not in ("kscan", "pair"):
@@ -91,11 +94,19 @@ class WorkModel:
         self.k = int(k)
         self.d = int(d)
         self.unit = unit
+        self.peaks = peaks_for(device_kind)
+        #: why rounds carry no roofline, or None when they do
+        self.no_roofline = (
+            None if self.peaks is not None else
+            f"no published peaks for device_kind {device_kind!r}; "
+            f"roofline and utilization not computed")
 
     @classmethod
-    def for_bounds(cls, k: int, d: int, bounds: str) -> "WorkModel":
+    def for_bounds(cls, k: int, d: int, bounds: str, *,
+                   device_kind: Optional[str] = None) -> "WorkModel":
         """The model whose unit matches a bound family's counter."""
-        return cls(k, d, unit=BOUNDS_WORK_UNIT.get(bounds, "kscan"))
+        return cls(k, d, unit=BOUNDS_WORK_UNIT.get(bounds, "kscan"),
+                   device_kind=device_kind)
 
     def pair_evals(self, n_recomputed: int) -> int:
         """``n_recomputed`` converted to pair-distance evaluations."""
@@ -115,20 +126,25 @@ class WorkModel:
         return F32_BYTES * (rows * self.d + self.k * self.d)
 
     def roofline(self, n_recomputed: int) -> Roofline:
+        if self.peaks is None:
+            raise ValueError(self.no_roofline)
         return roofline_terms(self.flops(n_recomputed),
-                              self.hbm_bytes(n_recomputed), 0.0)
+                              self.hbm_bytes(n_recomputed), 0.0,
+                              peaks=self.peaks)
 
     def round_work(self, n_recomputed: int,
                    dt_s: Optional[float] = None) -> RoundWork:
-        """Price a round; with ``dt_s`` also compute utilization."""
+        """Price a round; with ``dt_s`` and the chip's peaks also
+        compute utilization."""
         n = max(0, int(n_recomputed))
-        rl = self.roofline(n)
-        bound = rl.step_time_s()
-        util = None
-        if dt_s is not None and dt_s > 0.0:
-            util = bound / dt_s
+        bound = bottleneck = util = None
+        if self.peaks is not None:
+            rl = self.roofline(n)
+            bound, bottleneck = rl.step_time_s(), rl.bottleneck
+            if dt_s is not None and dt_s > 0.0:
+                util = bound / dt_s
         kscans = n if self.unit == "kscan" else -(-n // self.k)
         return RoundWork(kscans=kscans, dist_evals=self.pair_evals(n),
-                         flops=rl.flops, hbm_bytes=rl.hbm_bytes,
-                         bound_s=bound, bottleneck=rl.bottleneck,
+                         flops=self.flops(n), hbm_bytes=self.hbm_bytes(n),
+                         bound_s=bound, bottleneck=bottleneck,
                          dt_s=dt_s, utilization=util, unit=self.unit)

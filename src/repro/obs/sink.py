@@ -55,11 +55,15 @@ class FitObserver:
     elkan/exponion rounds count individual pair distances in
     ``n_recomputed`` (annulus scans, not full k rows), and pricing them
     as k-scans would overstate the work by exactly the pruning factor.
+    ``device_kind`` picks the chip's peaks; on a device with none
+    published (the CPU) the utilization gauge is not created and the
+    ``fit_start`` event carries the reason instead.
     """
 
     def __init__(self, trace_dir: Union[str, Path], *, process_id: int = 0,
                  k: Optional[int] = None, d: Optional[int] = None,
                  bounds: Optional[str] = None,
+                 device_kind: Optional[str] = None,
                  meta: Optional[Dict[str, Any]] = None,
                  registry: Optional[MetricsRegistry] = None,
                  rotate_bytes: int = 8 << 20):
@@ -67,7 +71,8 @@ class FitObserver:
                                  rotate_bytes=rotate_bytes)
         self.registry = registry if registry is not None else \
             MetricsRegistry()
-        self.work = (WorkModel.for_bounds(k, d, bounds or "hamerly2")
+        self.work = (WorkModel.for_bounds(k, d, bounds or "hamerly2",
+                                          device_kind=device_kind)
                      if k and d else None)
         self._closed = False
         self._tc_before = tracecount.snapshot()
@@ -84,12 +89,17 @@ class FitObserver:
             "fit_kscans_per_s", "last round's achieved k-scan rate")
         self._g_bytes = r.gauge(
             "fit_bytes_per_s", "last round's achieved HBM byte rate")
-        self._g_util = r.gauge(
-            "fit_roofline_utilization",
-            "last round's bound_s / wall_s vs the roofline model")
+        self._g_util = None
+        if self.work is not None and self.work.peaks is not None:
+            self._g_util = r.gauge(
+                "fit_roofline_utilization",
+                "last round's bound_s / wall_s vs the roofline model")
         self._g_b = r.gauge("fit_b_global", "current global nested batch")
         attrs = dict(meta or {})
-        attrs.update(obs_schema=OBS_SCHEMA, k=k, d=d)
+        attrs.update(obs_schema=OBS_SCHEMA, k=k, d=d,
+                     device_kind=device_kind)
+        if self.work is not None and self.work.no_roofline:
+            attrs["no_roofline"] = self.work.no_roofline
         self.tracer.event("fit_start", **attrs)
 
     # -- the ObsSink duck-type surface ---------------------------------------
@@ -140,7 +150,7 @@ class FitObserver:
             if dt_s > 0.0:
                 self._g_kscans.set(w.kscans / dt_s)
                 self._g_bytes.set(w.hbm_bytes / dt_s)
-            if w.utilization is not None:
+            if self._g_util is not None and w.utilization is not None:
                 self._g_util.set(w.utilization)
         if store:
             delta = {f"store_{key}": v - self._store_before.get(key, 0)

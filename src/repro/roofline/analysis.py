@@ -15,8 +15,10 @@ estimated *wire* volume per op (ring algorithms, large-n approximation):
   all-to-all        out_bytes
   collective-permute out_bytes             (one hop)
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (one link counted; conservative).
+Hardware model: the published peaks of one chip, keyed by the
+``device_kind`` JAX reports (`PEAKS`). A device missing from the table
+has no roofline: callers get None from `peaks_for` and say so, rather
+than borrowing another chip's numbers.
 """
 from __future__ import annotations
 
@@ -24,9 +26,33 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """One chip's published peaks."""
+    flops: float        # bf16 FLOP/s
+    hbm_bw: float       # HBM bytes/s
+    link_bw: float      # bytes/s of one ICI link
+    source: str
+
+
+#: ``device_kind`` as JAX reports it for a TPU v5e.
+V5E = "TPU v5 lite"
+
+#: Published per-chip peaks by ``device_kind``.
+PEAKS: Dict[str, ChipPeaks] = {
+    V5E: ChipPeaks(
+        flops=197e12, hbm_bw=819e9, link_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI (one of four "
+               "links counted: 50 GB/s)"),
+}
+
+
+def peaks_for(device_kind: Optional[str]) -> Optional[ChipPeaks]:
+    """The chip's peaks, or None when its ``device_kind`` is not in
+    `PEAKS` (the CPU among them)."""
+    return PEAKS.get(device_kind or "")
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
@@ -109,6 +135,7 @@ class Roofline:
     memory_s: float
     collective_s: float
     bottleneck: str
+    peaks: ChipPeaks
     model_flops: Optional[float] = None
     useful_ratio: Optional[float] = None
 
@@ -121,19 +148,20 @@ class Roofline:
         if not self.model_flops:
             return None
         t = self.step_time_s()
-        return (self.model_flops / PEAK_FLOPS) / t if t > 0 else None
+        return (self.model_flops / self.peaks.flops) / t if t > 0 else None
 
 
 def roofline_terms(flops: float, hbm_bytes: float, wire_bytes: float, *,
+                   peaks: ChipPeaks,
                    model_flops: Optional[float] = None) -> Roofline:
-    c = flops / PEAK_FLOPS
-    m = hbm_bytes / HBM_BW
-    x = wire_bytes / LINK_BW
+    c = flops / peaks.flops
+    m = hbm_bytes / peaks.hbm_bw
+    x = wire_bytes / peaks.link_bw
     dom = max((c, "compute"), (m, "memory"), (x, "collective"))[1]
     useful = (model_flops / flops) if (model_flops and flops) else None
     return Roofline(flops=flops, hbm_bytes=hbm_bytes, wire_bytes=wire_bytes,
                     compute_s=c, memory_s=m, collective_s=x,
-                    bottleneck=dom, model_flops=model_flops,
+                    bottleneck=dom, peaks=peaks, model_flops=model_flops,
                     useful_ratio=useful)
 
 

@@ -17,6 +17,14 @@ from __future__ import annotations
 import os
 import sys
 import warnings
+from pathlib import Path
+
+#: JAX reads this variable itself; where it is set, it names the cache.
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+#: the cache's one fixed home when the variable is unset: inside the
+#: checkout (git-ignored), so the path — part of every cache key — never
+#: moves between runs.
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def device_count_flag(n: int) -> str:
@@ -115,6 +123,23 @@ def apply_kernel_flags(platform: str, *, env: dict | None = None) -> str:
         return merge_xla_flags(*flags, env=env)
     e = os.environ if env is None else env
     return e.get("XLA_FLAGS", "")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses it and
+    nothing else is set here. Otherwise the cache goes to the fixed
+    `COMPILE_CACHE_DIR`. Every compile is kept, however short, so a
+    second run of an entry point compiles nothing. Entry points call
+    this; tests do not.
+    """
+    import jax
+    path = os.environ.get(COMPILE_CACHE_ENV) or str(COMPILE_CACHE_DIR)
+    if not os.environ.get(COMPILE_CACHE_ENV):
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 def set_platform(platform: str = "cpu") -> None:

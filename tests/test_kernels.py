@@ -1,5 +1,5 @@
 """Pallas kernels vs pure-jnp oracles, interpret=True shape/dtype sweeps;
-the `kernels.plan` dispatch layer; the compat alias version guard."""
+the `kernels.plan` dispatch layer and its TPU tile rules."""
 import os
 import subprocess
 import sys
@@ -118,7 +118,7 @@ def test_fused_nested_round_matches_ref(n, d, k):
     valid = jnp.asarray(rng.random(n) < 0.9)
     args = (x, c, a_prev, settled, d_keep, lb_keep, valid)
     a_p, d_p, lb_p, S_p, v_p, sse_p = fused_nested_round_pallas(
-        *args, bn=64, interpret=True)
+        *args, bn=128, interpret=True)
     a_r, d_r, lb_r, S_r, v_r, sse_r = fused_nested_round_ref(*args)
     np.testing.assert_array_equal(np.asarray(a_p), np.asarray(a_r))
     np.testing.assert_allclose(d_p, d_r, rtol=1e-4, atol=1e-4)
@@ -160,7 +160,7 @@ def test_resolve_plan_bucketing_and_cache():
 def test_plan_blocks_and_to_dict():
     plan = resolve_plan("pallas", b=4096, k=200, d=300)
     assert plan.bk == 128 and plan.bd in (128, 256)
-    assert 8 <= plan.bn <= 512
+    assert 128 <= plan.bn <= 1024 and plan.bn % 128 == 0
     assert plan.source in ("table", "tuned", "cached")
     d = plan.to_dict()
     assert d["backend"] == "pallas" and tuple(d["bucket"]) == plan.bucket
@@ -196,28 +196,68 @@ def test_ops_dispatch_through_plan_awkward_shapes():
     np.testing.assert_array_equal(np.asarray(a2), np.asarray(a_r))
 
 
-# -- compat version guard ----------------------------------------------------
+# -- TPU tile rules ----------------------------------------------------------
 
-def test_compiler_params_alias_version_guard():
-    """`kernels.compat.CompilerParams` must resolve on this jax, accept
-    the dimension_semantics the kernels pass, and — on jax >= 0.6,
-    where the rename landed upstream — be the new-name class itself."""
-    import jax
+def _call_assign(bn, bk):
+    x = jnp.zeros((8, 16), jnp.float32)
+    assign_top2_pallas(x, x[:4], bn=bn, bk=bk, interpret=True)
+
+
+def _call_cluster_sum(bn, bd):
+    x = jnp.zeros((8, 16), jnp.float32)
+    cluster_sum_pallas(x, jnp.zeros((8,), jnp.int32), 4, bn=bn, bd=bd,
+                       interpret=True)
+
+
+def _call_fused(bn, _unused):
+    from repro.kernels.fused_round import fused_round_pallas
+    x = jnp.zeros((8, 16), jnp.float32)
+    fused_round_pallas(x, x[:4], bn=bn, interpret=True)
+
+
+@pytest.mark.parametrize("call,tiles", [
+    (_call_assign, (64, 128)), (_call_assign, (128, 8)),
+    (_call_cluster_sum, (8, 128)), (_call_cluster_sum, (128, 200)),
+    (_call_fused, (64, None)), (_call_fused, (192 + 1, None)),
+])
+def test_wrappers_reject_non_tpu_tiles(call, tiles):
+    """Every wrapper refuses a tile the TPU compiler would refuse, in
+    interpret mode too, with an error that names the tile."""
+    with pytest.raises(ValueError, match="not a TPU tile"):
+        call(*tiles)
+
+
+@pytest.mark.parametrize("b", [1, 100, 5000, 400_000])
+@pytest.mark.parametrize("k,d", [(50, 784), (1024, 784), (4096, 128),
+                                 (16, 2048)])
+def test_table_blocks_are_tpu_tiles(b, k, d):
+    """The default plan only ever hands out legal tiles that fit VMEM:
+    lane multiples, and a (kp, bn) temporary / (bn, d) X tile within
+    `tile_fits` — bn shrinks as k or d grows instead of failing."""
+    from repro.kernels.plan import check_tile, tile_fits
+    plan = resolve_plan("pallas", b=b, k=k, d=d)
+    check_tile("plan", bn=plan.bn, bk=plan.bk, bd=plan.bd)
+    assert plan.source == "table"
+    assert tile_fits(plan.bn, k, d) or plan.bn == 128
+    if (b, k, d) == (400_000, 50, 784):
+        assert plan.bn == 1024
+    if (b, k, d) == (400_000, 1024, 784):
+        assert plan.bn == 256
+
+
+def test_kernels_use_pltpu_compiler_params():
+    """The kernels build `pltpu.CompilerParams` directly, with the
+    dimension semantics and an explicit scoped-VMEM limit."""
     from jax.experimental.pallas import tpu as pltpu
 
-    from repro.kernels.compat import CompilerParams
-    assert CompilerParams is not None
-    cp = CompilerParams(dimension_semantics=("arbitrary",))
+    from repro.kernels.plan import VMEM_CAP_BYTES, vmem_limit_bytes
+    cp = pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                              vmem_limit_bytes=vmem_limit_bytes(
+                                  "t", blocks=[(256, 784)], temps=[]))
     assert tuple(cp.dimension_semantics) == ("arbitrary",)
-    major, minor = (int(v) for v in jax.__version__.split(".")[:2])
-    if (major, minor) >= (0, 6):
-        assert hasattr(pltpu, "CompilerParams"), \
-            "jax >= 0.6 must ship pltpu.CompilerParams"
-        assert CompilerParams is pltpu.CompilerParams
-    else:
-        assert CompilerParams in (
-            getattr(pltpu, "CompilerParams", None),
-            getattr(pltpu, "TPUCompilerParams", None))
+    assert 16 << 20 <= cp.vmem_limit_bytes <= VMEM_CAP_BYTES
+    with pytest.raises(ValueError, match="VMEM"):
+        vmem_limit_bytes("t", blocks=[(8192, 8192)], temps=[])
 
 
 # -- the end-to-end smoke ----------------------------------------------------

@@ -144,6 +144,21 @@ def test_capacity_compaction_is_exact(blobs):
                                       np.asarray(s_b.points.a[:b]))
 
 
+@pytest.mark.parametrize("b,capacity,p", [
+    (1024, 256, 0.1), (5000, 1024, 0.3), (1537, 1536, 0.9),
+    (700, 64, 0.0), (700, 64, 1.0), (100_000, 16384, 0.05)])
+def test_needs_first_matches_stable_argsort(b, capacity, p):
+    """The sort-free compaction order is the stable argsort's, exactly:
+    rescanned rows first, settled rows after, each in row order —
+    across block boundaries (b % 512 != 0), and with none or all rows
+    needing a rescan."""
+    rng = np.random.default_rng(b + capacity)
+    needs = rng.random(b) < p
+    want = np.argsort(np.where(needs, 0, 1), kind="stable")[:capacity]
+    got = rounds._needs_first(jnp.asarray(needs), capacity)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 # ---------------------------------------------------------------------------
 # mb: S/v vectorised form == serial Alg. 1 oracle
 # ---------------------------------------------------------------------------

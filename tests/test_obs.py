@@ -185,13 +185,19 @@ def test_serve_metrics_schema_byte_compatible():
 
 
 def test_workmodel_prices_rounds():
-    w = WorkModel(k=50, d=64)
+    from repro.roofline.analysis import V5E
+    w = WorkModel(k=50, d=64, device_kind=V5E)
     rw = w.round_work(1000, dt_s=0.01)
     assert rw.kscans == 1000 and rw.dist_evals == 50_000
     assert rw.flops == 3.0 * 64 * 50_000
     assert rw.hbm_bytes == 4 * (1000 * 64 + 50 * 64)
     assert rw.bound_s > 0 and 0 < rw.utilization < 1
     assert w.round_work(0).dist_evals == 0
+    # a device with no published peaks: counts only, and a stated reason
+    cpu = WorkModel(k=50, d=64, device_kind="cpu").round_work(1000, 0.01)
+    assert cpu.flops == rw.flops and cpu.hbm_bytes == rw.hbm_bytes
+    assert cpu.bound_s is None and cpu.utilization is None
+    assert "no published peaks" in WorkModel(k=50, d=64).no_roofline
 
 
 def test_obs_package_is_accelerator_free():
@@ -231,14 +237,20 @@ def test_telemetry_json_roundtrip_nonfinite():
 # the instrumented fit (local backend; the smoke covers the rest)
 # ---------------------------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def traced_fit(tmp_path_factory):
+@pytest.fixture(scope="module", params=["host", "v5e"])
+def traced_fit(request, tmp_path_factory):
+    """A traced local fit. ``host``: the observer gets this process's
+    own device_kind (the CPU: no published peaks). ``v5e``: the test
+    hands it the v5e's device_kind, so rounds are priced against the
+    v5e roofline."""
+    import jax
     import numpy as np
 
     from repro.api.config import FitConfig
     from repro.api.engines import make_engine
     from repro.api.loop import run_loop
     from repro.obs import FitObserver
+    from repro.roofline.analysis import V5E
 
     td = tmp_path_factory.mktemp("trace")
     rng = np.random.default_rng(0)
@@ -249,13 +261,17 @@ def traced_fit(tmp_path_factory):
                        eval_every=4, capacity_floor=32).resolve(n)
     run = make_engine(config).begin(X, config, X_val=X_val)
     schedule = []
-    with FitObserver(td, k=k, d=d, meta={"backend": "local"}) as obs:
+    kind = (V5E if request.param == "v5e"
+            else jax.devices()[0].device_kind)
+    with FitObserver(td, k=k, d=d, device_kind=kind,
+                     meta={"backend": "local"}) as obs:
         out = run_loop(run, config, trace=schedule, obs=obs)
-    return td, out, schedule
+    return td, out, schedule, kind
 
 
 def test_round_events_match_schedule_trace(traced_fit):
-    td, out, schedule = traced_fit
+    from repro.roofline.analysis import peaks_for
+    td, out, schedule, kind = traced_fit
     ev = read_events(td)
     rounds = [e for e in ev if e.get("name") == "round"]
     assert len(rounds) == len(schedule) > 0
@@ -265,23 +281,35 @@ def test_round_events_match_schedule_trace(traced_fit):
     s = summarize(ev)
     assert s["rounds"] == len(schedule)
     assert s["kscans_total"] == sum(r.n_recomputed for r in out.telemetry)
-    # the roofline gauge priced at least one round
-    assert all(e["attrs"]["utilization"] is None
-               or 0 < e["attrs"]["utilization"] <= 1 for e in rounds)
-    assert any(e["attrs"]["utilization"] is not None for e in rounds)
+    start = next(e for e in ev if e.get("name") == "fit_start")
+    assert start["attrs"]["device_kind"] == kind
+    if peaks_for(kind) is None:
+        # no chip peaks: no utilization, and the reason is on record
+        assert all(e["attrs"]["utilization"] is None for e in rounds)
+        assert "no published peaks" in start["attrs"]["no_roofline"]
+    else:
+        # the roofline gauge priced at least one round
+        assert all(e["attrs"]["utilization"] is None
+                   or 0 < e["attrs"]["utilization"] <= 1 for e in rounds)
+        assert any(e["attrs"]["utilization"] is not None for e in rounds)
+        assert "no_roofline" not in start["attrs"]
     names = {e.get("name") for e in ev}
     assert {"fit_start", "fit_end", "round"} <= names
 
 
 def test_metrics_json_written_at_close(traced_fit):
-    td, out, schedule = traced_fit
+    from repro.roofline.analysis import peaks_for
+    td, out, schedule, kind = traced_fit
     path = td / "metrics-p00000.json"
     m = json.loads(path.read_text())
     assert m["counters"]["fit_rounds"] == len(schedule)
     assert m["counters"]["fit_kscans"] == sum(
         r.n_recomputed for r in out.telemetry)
     assert m["histograms"]["fit_round_seconds"]["count"] == len(schedule)
-    assert 0 < m["gauges"]["fit_roofline_utilization"] <= 1
+    if peaks_for(kind) is None:
+        assert "fit_roofline_utilization" not in m["gauges"]
+    else:
+        assert 0 < m["gauges"]["fit_roofline_utilization"] <= 1
 
 
 def test_estimator_telemetry_roundtrip(tmp_path, blobs, blobs_val):

@@ -79,7 +79,7 @@ ENTRY %main (p: f32[16]) -> f32[16] {
 
 def test_roofline_terms_and_bottleneck():
     r = ra.roofline_terms(197e12, 819e9 * 2, 50e9 * 0.5,
-                          model_flops=98.5e12)
+                          peaks=ra.PEAKS[ra.V5E], model_flops=98.5e12)
     assert r.compute_s == pytest.approx(1.0)
     assert r.memory_s == pytest.approx(2.0)
     assert r.collective_s == pytest.approx(0.5)
