@@ -16,6 +16,17 @@ from repro.obs import OBS_SCHEMA
 # `record_manifest` themselves with the child's resolved configs.
 MANIFESTS: List[dict] = []
 
+#: bound families whose ``n_recomputed`` counts single (point, centroid)
+#: distances (elkan's per-pair test, exponion's annulus); the others
+#: count points scanned against all k centroids ("kscan")
+PAIR_COUNTED_BOUNDS = ("elkan", "exponion")
+
+
+def pair_dist_evals(n_recomputed: int, k: int, bounds: str) -> int:
+    """``n_recomputed`` of a ``bounds`` fit in pair-distance evals."""
+    n = max(0, int(n_recomputed))
+    return n if bounds in PAIR_COUNTED_BOUNDS else n * k
+
 
 def record_manifest(suite: str, config_dict: dict, *,
                     wall_s: Optional[float] = None,

@@ -11,7 +11,7 @@ Two cost metrics per family — both recorded, nothing hidden:
   * ``pair_dist_evals`` — actual (point, centroid) distance
     evaluations a serial implementation performs. ``n_recomputed`` is
     counted in the family's native unit (kscan for none/hamerly2, pair
-    for elkan/exponion — `repro.obs.efficiency.BOUNDS_WORK_UNIT`) and
+    for elkan/exponion — `common.PAIR_COUNTED_BOUNDS`) and
     unit-converted here.
   * ``serial_pair_work`` — distance evals PLUS per-pair bound
     maintenance. For elkan this adds b*k per round: a serial elkan
@@ -44,7 +44,6 @@ import numpy as np
 from benchmarks import common
 from repro import api
 from repro.api.config import bound_state_bytes
-from repro.obs.efficiency import WorkModel
 
 ART = Path(__file__).resolve().parent.parent / "artifacts" / "bench"
 
@@ -56,10 +55,9 @@ def _run_family(X, X_val, *, k: int, bounds: str, max_rounds: int):
                         bounds=bounds, max_rounds=max_rounds,
                         eval_every=1, seed=0)
     res = api.fit(X, cfg, X_val=X_val)
-    wm = WorkModel.for_bounds(k, X.shape[-1], bounds)
     evals, work, pruned, b_curve, val = [], [], [], [], []
     for t in res.telemetry:
-        e = wm.pair_evals(t.n_recomputed)
+        e = common.pair_dist_evals(t.n_recomputed, k, bounds)
         w = e + (t.b * k if bounds == "elkan" else 0)  # bound walk
         evals.append(int(e))
         work.append(int(w))

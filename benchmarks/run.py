@@ -46,55 +46,33 @@ def main() -> int:
     current = {"suite": None}
     orig_fit = api.fit
 
-    def harvest_utilization(trace_dir):
-        """Last-round `fit_roofline_utilization` gauge from the trace
-        dir's metrics export (max across processes on multihost)."""
-        if trace_dir is None:
-            return None
-        vals = []
-        for f in sorted(Path(trace_dir).glob("metrics-p*.json")):
-            try:
-                g = json.loads(f.read_text()).get("gauges", {})
-            except (OSError, ValueError):
-                continue
-            if g.get("fit_roofline_utilization") is not None:
-                vals.append(float(g["fit_roofline_utilization"]))
-        return max(vals) if vals else None
-
     def recording_fit(X, config, **kw):
         tc0 = tracecount.snapshot()
         t0 = time.perf_counter()
         out = orig_fit(X, config, **kw)
         wall = time.perf_counter() - t0
-        util = harvest_utilization(out.config.trace_dir)
         cfg = out.config
         # n_recomputed's unit depends on the bound family (kscan vs
         # pair) — record the family, the unit, and the unit-converted
         # pair-distance total so manifests compare across families.
         from repro.api.config import bound_state_bytes
-        from repro.obs.efficiency import WorkModel
-        wm = WorkModel.for_bounds(cfg.k, X.shape[-1], cfg.bounds)
         n_rec_total = int(sum(r.n_recomputed for r in out.telemetry))
         obs = {
             "rounds": len(out.telemetry),
             "kscans_total": n_rec_total,
             "bounds_family": cfg.bounds,
-            "work_unit": wm.unit,
-            "pair_dist_evals": wm.pair_evals(n_rec_total),
+            "work_unit": ("pair" if cfg.bounds
+                          in common.PAIR_COUNTED_BOUNDS else "kscan"),
+            "pair_dist_evals": common.pair_dist_evals(
+                n_rec_total, cfg.k, cfg.bounds),
             "bound_state_bytes": bound_state_bytes(
                 cfg.bounds, len(X), cfg.k),
             "retrace_count": int(sum(tracecount.diff(tc0).values())),
             "peak_queue_depth": None,
-            "fit_roofline_utilization": util,
         }
         nulls = {"peak_queue_depth":
                  "batch fit — no ingest queue in the path (the serve "
                  "suite records its queue's high-water mark)"}
-        if util is None:
-            nulls["fit_roofline_utilization"] = (
-                "no trace_dir on this fit, or no published peaks for "
-                "its device_kind — the roofline gauge lives in the obs "
-                "metrics export, on devices in roofline.analysis.PEAKS")
         common.record_manifest(
             current["suite"], out.config.to_dict(),
             wall_s=round(wall, 3), obs=obs,

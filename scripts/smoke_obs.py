@@ -69,7 +69,8 @@ def main():
         td = tempfile.mkdtemp(prefix=f"smoke-obs-{backend}-")
         out, schedule = traced_fit(backend, td)
         events = read_events(td)
-        rounds = [e for e in events if e.get("name") == "round"]
+        rounds = [e for e in events if e.get("ph") == "event"
+                  and e.get("name") == "round"]
         # tb fits append one schedule-trace entry per in-loop round,
         # and the observer emits one "round" event per in-loop round:
         # the two independently-built records must agree exactly

@@ -27,23 +27,93 @@ from repro.api.config import FitConfig
 from repro.core.state import ClusterStats, KMeansState, RoundInfo
 
 
-class _NullObsSink:
-    """Default obs sink: every engine hook is a guaranteed no-op.
+def profiler_span(name: str):
+    """The profiler's annotation of program region ``name``.
 
-    This is deliberately NOT `api.loop.ObsSink` (loop imports this
-    module; importing loop back would cycle) — just the two hooks an
-    engine body ever touches. `run_loop` swaps in the real sink via
-    `EngineRun.bind_obs` before the first round.
+    Opens ``repro.<name>`` (``repro.<layer>[.<stage>]``) on the host
+    plane of any `jax.profiler` trace, on the profiler's own clock, so
+    the region lies over the device ops it waits for. With no trace
+    running it costs about a microsecond. Carries no attributes: a
+    `TraceAnnotation` with keyword arguments costs half as much again
+    even when the profiler is off.
+    """
+    return jax.profiler.TraceAnnotation("repro." + name)
+
+
+class ObsSink:
+    """Observability seam for `repro.obs` — sibling of `LoopAudit`.
+
+    `run_loop` hands every completed round's HOST-landed scalars (the
+    `HostRoundInfo`, the schedule's b/capacity/patience values, the
+    work-clock delta, the data-store read counters) to ``round_end``,
+    brackets every stage of a fit (the engine's set-up, each round's
+    dispatch / wait / info / record, eval, checkpoint, store ingest)
+    with ``span``, and notes overflow retries with ``count``.
+
+    The base class writes nothing: its ``span`` opens the profiler's
+    annotation (`profiler_span`) and the rest are no-ops, so an
+    untraced fit pays a few method calls per ROUND — nothing per point,
+    and nothing on a device — and any profiler trace of it shows its
+    stages. A subclass that overrides ``span`` keeps that by opening
+    ``super().span(...)``. The JSONL implementation is
+    `repro.obs.FitObserver`, which this seam deliberately does not
+    import: `as_sink` wraps it in `ProfiledSink`. Observers consume only
+    values that already crossed at a sanctioned point, so
+    instrumentation can never add a device->host sync — the hostsync
+    auditor runs with tracing ON to prove it.
     """
 
     def span(self, name: str, **attrs):
-        return contextlib.nullcontext()
+        return profiler_span(name)
 
     def count(self, name: str, n: int = 1) -> None:
         pass
 
+    def round_end(self, round: int, hinfo: Any, **attrs) -> None:
+        pass
 
-_NO_OBS = _NullObsSink()
+    def fit_end(self, **summary) -> None:
+        pass
+
+    def close(self) -> None:
+        pass
+
+
+class ProfiledSink(ObsSink):
+    """A duck-typed sink (`repro.obs.FitObserver`) whose spans also
+    open the profiler's annotation, around its own, under one name."""
+
+    def __init__(self, sink: Any):
+        self.sink = sink
+
+    @contextlib.contextmanager
+    def span(self, name: str, **attrs):
+        with profiler_span(name), self.sink.span(name, **attrs):
+            yield
+
+    def count(self, name: str, n: int = 1) -> None:
+        self.sink.count(name, n)
+
+    def round_end(self, round: int, hinfo: Any, **attrs) -> None:
+        self.sink.round_end(round, hinfo, **attrs)
+
+    def fit_end(self, **summary) -> None:
+        self.sink.fit_end(**summary)
+
+    def close(self) -> None:
+        self.sink.close()
+
+
+NULL_OBS = ObsSink()
+
+
+def as_sink(obs: Any) -> ObsSink:
+    """``obs`` as the loop and the engines call it: the profiler-only
+    `NULL_OBS` for None, an `ObsSink` as it is, any other sink wrapped
+    in `ProfiledSink`."""
+    if obs is None:
+        return NULL_OBS
+    return obs if isinstance(obs, ObsSink) else ProfiledSink(obs)
 
 
 class EngineRun:
@@ -107,16 +177,16 @@ class EngineRun:
     # -- observability (see repro.obs; default: no-ops) ---------------------
 
     #: the bound obs sink; engine bodies call ``self._obs.span(...)`` /
-    #: ``self._obs.count(...)`` unconditionally — the null sink makes
-    #: untraced fits pay two attribute loads, nothing more.
-    _obs: Any = _NO_OBS
+    #: ``self._obs.count(...)`` unconditionally — the default sink
+    #: writes nothing and only opens the profiler's annotation.
+    _obs: ObsSink = NULL_OBS
 
     def bind_obs(self, obs: Any) -> None:
-        """Attach the fit's obs sink (called once by `run_loop` before
-        round 0). The sink must only ever be handed HOST values — an
-        engine must never pass it a live device array (the hostsync
-        auditor enforces this on instrumented fits)."""
-        self._obs = obs if obs is not None else _NO_OBS
+        """Attach the fit's obs sink (by `Engine.begin`, and again by
+        `run_loop` before round 0). The sink must only ever be handed
+        HOST values — an engine must never pass it a live device array
+        (the hostsync auditor enforces this on instrumented fits)."""
+        self._obs = as_sink(obs)
 
     def store_metrics(self) -> Optional[Dict[str, Any]]:
         """Cumulative `repro.data.store` read metrics as a JSON-safe
@@ -225,6 +295,10 @@ class Engine(Protocol):
     """An execution backend: owns data placement + compiled rounds."""
 
     def begin(self, X, config: FitConfig, *,
-              X_val=None, init_C: Optional[np.ndarray] = None) -> EngineRun:
-        """Shuffle/pad/place ``X`` and build the initial state."""
+              X_val=None, init_C: Optional[np.ndarray] = None,
+              obs: Any = None) -> EngineRun:
+        """Shuffle/pad/place ``X`` and build the initial state. The run
+        is bound to ``obs``, the fit's sink; the local engine binds it
+        first, so its set-up stages (``fit.shuffle``, ``fit.to_device``,
+        ``fit.init``) reach it."""
         ...

@@ -35,45 +35,62 @@ _IO_SEG_ROWS = 65536
 
 
 class _LocalRun(EngineRun):
-    def __init__(self, X, config: FitConfig, X_val, init_C):
+    def __init__(self, X, config: FitConfig, X_val, init_C, obs=None):
         from repro.data.store import (ChunkStore, dataset_fingerprint,
                                       store_permutation)
+        self.bind_obs(obs)
         rng = np.random.default_rng(config.seed)
         self._store = X if isinstance(X, ChunkStore) else None
-        if self._store is not None:
-            # out-of-core: a zero device buffer filled lazily to the
-            # current nested prefix (`_ensure_prefix`); the host never
-            # holds more than one fetch segment of rows at a time. The
-            # chunk-blocked permutation keeps the disk frontier
-            # sequential — see repro.data.store.source.
-            N = self._store.n
-            perm = store_permutation(N, self._store.chunk_rows,
-                                     config.seed, shuffle=config.shuffle)
-            self._Xd = jnp.zeros((N, self._store.d), jnp.float32)
-            self._filled = 0
-            # shared donated segment writer (repro.util.device): the
-            # donation auditor proves it aliases rather than copies
-            self._upd = piece_update
-            self.data_fingerprint = self._store.fingerprint()
-        else:
-            X = np.asarray(X)
-            N = X.shape[0]
-            perm = rng.permutation(N) if config.shuffle else np.arange(N)
-            self._Xd = jnp.asarray(X[perm])
-            self._filled = N
-            self.data_fingerprint = dataset_fingerprint(X)
-        self._Xv = jnp.asarray(X_val) if X_val is not None else None
+        with self._obs.span("fit.shuffle"):
+            if self._store is not None:
+                # out-of-core: the chunk-blocked permutation keeps the
+                # disk frontier sequential — see repro.data.store.source
+                N = self._store.n
+                perm = store_permutation(N, self._store.chunk_rows,
+                                         config.seed,
+                                         shuffle=config.shuffle)
+            else:
+                X = np.asarray(X)
+                N = X.shape[0]
+                perm = (rng.permutation(N) if config.shuffle
+                        else np.arange(N))
+                rows = X[perm]
+        with self._obs.span("fit.to_device"):
+            if self._store is not None:
+                # a zero device buffer filled lazily to the current
+                # nested prefix (`_ensure_prefix`); the host never holds
+                # more than one fetch segment of rows at a time. The
+                # shared donated segment writer (repro.util.device)
+                # fills it: the donation auditor proves it aliases
+                # rather than copies
+                self._Xd = jnp.zeros((N, self._store.d), jnp.float32)
+                self._filled = 0
+                self._upd = piece_update
+            else:
+                self._Xd = jnp.asarray(rows)
+                del rows
+                self._filled = N
+            self._Xv = jnp.asarray(X_val) if X_val is not None else None
         self._config = config
         self._rng = rng
         self._perm = perm
-        if self._store is not None:
-            # paper init needs the first k shuffled rows materialised
-            self._ensure_prefix(min(N, max(config.k, 1)))
-
-        state = init_state(self._Xd, config.k, bounds=config.bounds)
-        if init_C is not None:       # warm start (checkpoint restart)
-            state = dataclasses.replace(state, stats=dataclasses.replace(
-                state.stats, C=jnp.asarray(init_C, jnp.float32)))
+        with self._obs.span("fit.init"):
+            if self._store is not None:
+                self.data_fingerprint = self._store.fingerprint()
+                # paper init needs the first k shuffled rows materialised
+                self._ensure_prefix(min(N, max(config.k, 1)))
+            else:
+                self.data_fingerprint = dataset_fingerprint(X)
+            state = init_state(self._Xd, config.k, bounds=config.bounds)
+            if init_C is not None:       # warm start (checkpoint restart)
+                state = dataclasses.replace(
+                    state, stats=dataclasses.replace(
+                        state.stats, C=jnp.asarray(init_C, jnp.float32)))
+            # kernel dispatch: resolved ONCE for the fit at its maximum
+            # batch bucket; every round below threads this plan
+            self.kernel_plan = resolve_plan(
+                config.kernel_backend, b=N, k=config.k,
+                d=self._Xd.shape[1], bounds=config.bounds)
         self.state = state
         self.b = min(config.b0, N)
         self.b_max = N
@@ -81,11 +98,6 @@ class _LocalRun(EngineRun):
         self.n_active_target = N
         self.orig_index = perm        # storage row i holds X[perm[i]]
         self.n_points = N
-        # kernel dispatch: resolved ONCE for the fit at its maximum
-        # batch bucket; every round below threads this plan
-        self.kernel_plan = resolve_plan(config.kernel_backend, b=N,
-                                        k=config.k, d=self._Xd.shape[1],
-                                        bounds=config.bounds)
         # mb/mbf resampling stream (paper footnote 1: cycle a reshuffle)
         self._mb_pos = 0
         self._mb_perm = rng.permutation(N)
@@ -189,5 +201,5 @@ class LocalEngine:
     """Single-process engine over the bucketed-jit round functions."""
 
     def begin(self, X, config: FitConfig, *, X_val=None,
-              init_C=None) -> EngineRun:
-        return _LocalRun(X, config, X_val, init_C)
+              init_C=None, obs=None) -> EngineRun:
+        return _LocalRun(X, config, X_val, init_C, obs)

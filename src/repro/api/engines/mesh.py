@@ -380,5 +380,7 @@ class MeshEngine:
         self.mesh = mesh
 
     def begin(self, X, config: FitConfig, *, X_val=None,
-              init_C=None) -> EngineRun:
-        return _MeshRun(X, config, self.mesh, X_val, init_C)
+              init_C=None, obs=None) -> EngineRun:
+        run = _MeshRun(X, config, self.mesh, X_val, init_C)
+        run.bind_obs(obs)
+        return run

@@ -189,10 +189,12 @@ class MultiHostEngine:
         self.mesh = mesh
 
     def begin(self, X, config: FitConfig, *, X_val=None,
-              init_C=None) -> EngineRun:
+              init_C=None, obs=None) -> EngineRun:
         if self.mesh is None:
             from repro.launch.mesh import (ensure_multihost_initialized,
                                            make_multihost_mesh)
             ensure_multihost_initialized(config)
             self.mesh = make_multihost_mesh(config.data_axes)
-        return _MultiHostRun(X, config, self.mesh, X_val, init_C)
+        run = _MultiHostRun(X, config, self.mesh, X_val, init_C)
+        run.bind_obs(obs)
+        return run

@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.api.config import FitConfig
 from repro.api.engines import Engine, make_engine, nested_jit
+from repro.api.engines.base import as_sink
 from repro.api.loop import FitOutcome, fetch_round_info, run_loop
 from repro.api.telemetry import RoundCallback, Telemetry
 from repro.checkpoint.store import CheckpointStore
@@ -122,54 +123,34 @@ class NestedKMeans:
             if resume and cfg.checkpoint is None:
                 raise ValueError(
                     "fit(resume=True) requires config.checkpoint")
-            run = self.engine.begin(X, cfg, X_val=X_val, init_C=init_C)
-            obs = None
+            observer = None
             if cfg.trace_dir is not None:
                 # built lazily so untraced fits never import repro.obs;
                 # process_id keys the per-process JSONL files on
                 # multihost (every process traces its own host loop)
                 from repro.obs import FitObserver
-                obs = FitObserver(
+                observer = FitObserver(
                     cfg.trace_dir, process_id=jax.process_index(),
-                    k=cfg.k, d=int(run.state.stats.C.shape[-1]),
-                    bounds=cfg.bounds,
+                    k=cfg.k,
+                    d=int(X.d if isinstance(X, ChunkStore)
+                          else np.shape(X)[-1]),
                     device_kind=jax.devices()[0].device_kind,
                     meta={"backend": cfg.backend,
                           "algorithm": cfg.algorithm,
                           "bounds": cfg.bounds,
-                          "n_points": run.n_points,
-                          "n_shards": run.n_shards, "seed": cfg.seed})
-            resume_from = None
-            resolved = None
-            if resume:
-                store = CheckpointStore(cfg.checkpoint.checkpoint_dir,
-                                        keep=cfg.checkpoint.keep)
-                # the resume decision goes through the run so it is
-                # process-replicated: on multihost the coordinator's
-                # filesystem is the source of truth and its verdict is
-                # broadcast — no process can start fresh while another
-                # restores
-                step, extra = run.resolve_resume(store)
-                if step is not None:
-                    saved = (extra or {}).get("config")
-                    if saved:
-                        want = cfg.to_dict()
-                        bad = [k for k in _RESUME_KEYS
-                               if k in saved and saved[k] != want[k]]
-                        if bad:
-                            raise ValueError(
-                                f"checkpoint manifest disagrees with the "
-                                f"resuming config on {bad}; refusing to "
-                                f"restore a foreign fit")
-                    resume_from = store
-                    resolved = (step, extra)
+                          "n_points": n, "seed": cfg.seed})
+            obs = as_sink(observer)
             try:
+                with obs.span("fit.begin"):
+                    run = self.engine.begin(X, cfg, X_val=X_val,
+                                            init_C=init_C, obs=obs)
+                resume_from, resolved = (self._resume_point(run, cfg)
+                                         if resume else (None, None))
                 out = run_loop(run, cfg, on_round=self.on_round,
                                resume_from=resume_from,
                                resolved_resume=resolved, obs=obs)
             finally:
-                if obs is not None:
-                    obs.close()
+                obs.close()
             self._outcome = out
             # fetch_stats: the state's own leaves on single-process
             # engines; a host gather on multihost (so predict/export
@@ -180,6 +161,30 @@ class NestedKMeans:
             # outcome's own telemetry history
             self.telemetry_ = list(out.telemetry)
             return self
+
+    @staticmethod
+    def _resume_point(run, cfg: FitConfig):
+        """(store, (step, extra)) of the checkpoint to resume from, or
+        (None, None) when ``checkpoint_dir`` holds none yet."""
+        store = CheckpointStore(cfg.checkpoint.checkpoint_dir,
+                                keep=cfg.checkpoint.keep)
+        # the resume decision goes through the run so it is
+        # process-replicated: on multihost the coordinator's filesystem
+        # is the source of truth and its verdict is broadcast — no
+        # process can start fresh while another restores
+        step, extra = run.resolve_resume(store)
+        if step is None:
+            return None, None
+        saved = (extra or {}).get("config")
+        if saved:
+            want = cfg.to_dict()
+            bad = [k for k in _RESUME_KEYS
+                   if k in saved and saved[k] != want[k]]
+            if bad:
+                raise ValueError(
+                    f"checkpoint manifest disagrees with the resuming "
+                    f"config on {bad}; refusing to restore a foreign fit")
+        return store, (step, extra)
 
     def partial_fit(self, X) -> "NestedKMeans":
         """Fold one streaming batch into the codebook (one nested round).

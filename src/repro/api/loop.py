@@ -67,7 +67,7 @@ import jax
 import numpy as np
 
 from repro.api.config import FitConfig
-from repro.api.engines.base import EngineRun
+from repro.api.engines.base import EngineRun, ObsSink, as_sink
 from repro.api.telemetry import RoundCallback, Telemetry, final_val_mse
 from repro.checkpoint.store import CheckpointStore
 from repro.core.state import KMeansState, RoundInfo
@@ -144,44 +144,6 @@ class LoopAudit:
 
 _NULL_AUDIT = LoopAudit()
 
-
-class ObsSink:
-    """Observability seam for `repro.obs` — sibling of `LoopAudit`.
-
-    `run_loop` hands every completed round's HOST-landed scalars (the
-    `HostRoundInfo`, the schedule's b/capacity/patience values, the
-    work-clock delta, the data-store read counters) to ``round_end``,
-    brackets eval/checkpoint (and, via `EngineRun.bind_obs`, store
-    ingest) with ``span``, and notes overflow retries with ``count``.
-
-    The base class is a no-op, so untraced fits pay a few method calls
-    per ROUND — nothing per point, and nothing on a device. The real
-    implementation is `repro.obs.FitObserver` (structured JSONL traces,
-    a metrics registry, the roofline utilization gauge), which this
-    seam deliberately does not import: observers consume only values
-    that already crossed at a sanctioned point, so instrumentation can
-    never add a device->host sync — the hostsync auditor runs with
-    tracing ON to prove it.
-    """
-
-    def span(self, name: str, **attrs):
-        return contextlib.nullcontext()
-
-    def count(self, name: str, n: int = 1) -> None:
-        pass
-
-    def round_end(self, round: int, hinfo: "HostRoundInfo",
-                  **attrs) -> None:
-        pass
-
-    def fit_end(self, **summary) -> None:
-        pass
-
-    def close(self) -> None:
-        pass
-
-
-_NULL_OBS = ObsSink()
 
 
 # --------------------------------------------------------------------------
@@ -270,15 +232,21 @@ def run_loop(run: EngineRun, config: FitConfig, *,
     and its sanctioned device<->host crossings (the host-sync auditor's
     hook). ``None`` uses the no-op scopes.
 
-    ``obs``: optional `ObsSink` receiving each round's host-landed
-    scalars, span timings (eval / checkpoint / store ingest) and
-    overflow-retry counts — usually a `repro.obs.FitObserver`. ``None``
-    uses the no-op sink. The loop does NOT close the sink; its creator
-    does (the estimator closes the observer it built from
-    ``config.trace_dir``).
+    ``obs``: optional sink receiving each round's host-landed scalars,
+    span timings and overflow-retry counts — usually a
+    `repro.obs.FitObserver`, wrapped by `as_sink` so its spans also
+    reach the profiler. ``None`` uses the default sink, whose spans
+    reach the profiler alone. The spans: ``round`` (one iteration of
+    the round loop) holding ``round.dispatch`` (each step call, again
+    on an overflow retry), ``round.wait`` (the device finishing it),
+    ``round.info`` (`fetch_round_info`) and ``round.record``
+    (telemetry, the sink, ``on_round``; ``eval_mse`` nests in it);
+    ``checkpoint``; ``fit.finish`` (final eval, labels, stats). The
+    loop does NOT close the sink; its creator does (the estimator
+    closes the observer it built from ``config.trace_dir``).
     """
     audit = audit if audit is not None else _NULL_AUDIT
-    obs = obs if obs is not None else _NULL_OBS
+    obs = as_sink(obs)
     algorithm = config.algorithm
     bounds = config.bounds
     state = run.state
@@ -392,10 +360,26 @@ def run_loop(run: EngineRun, config: FitConfig, *,
                        background=ckpt.background)
         run.barrier()
 
+    def step() -> Tuple[KMeansState, HostRoundInfo]:
+        """Dispatch one round on ``state``, wait for it, land its
+        `RoundInfo`: the round's state and its host scalars."""
+        with obs.span("round.dispatch"):
+            if algorithm == "lloyd":
+                new_state, info = run.lloyd_step(state)
+            elif algorithm in ("mb", "mbf"):
+                new_state, info = run.mb_step(
+                    state, fixed=(algorithm == "mbf"))
+            else:  # tb family (incl. gb via bounds="none")
+                new_state, info = run.nested_step(state, b, capacity)
+        with obs.span("round.wait"):
+            jax.block_until_ready(new_state.stats.C)
+        with audit.sanctioned_scope("round_info"), obs.span("round.info"):
+            return new_state, fetch_round_info(info)
+
     for _ in range(start_round, config.max_rounds):
         if converged:        # resumed an already-finished fit
             break
-        with audit.round_scope():
+        with audit.round_scope(), obs.span("round"):
             if timed:
                 # the wall clock is the one host-local input to the
                 # schedule: the coordinator decides, every process obeys
@@ -405,36 +389,21 @@ def run_loop(run: EngineRun, config: FitConfig, *,
                 if out_of_time:
                     break
             t0 = time.perf_counter()
-
-            if algorithm == "lloyd":
-                new_state, info = run.lloyd_step(state)
-            elif algorithm in ("mb", "mbf"):
-                new_state, info = run.mb_step(
-                    state, fixed=(algorithm == "mbf"))
-            else:  # tb family (incl. gb via bounds="none")
-                while True:
-                    new_state, info = run.nested_step(state, b, capacity)
-                    jax.block_until_ready(new_state.stats.C)
-                    with audit.sanctioned_scope("round_info"):
-                        hinfo = fetch_round_info(info)
-                    if not hinfo.overflow:
-                        break
-                    # overflow retry: same input state, doubled bucket —
-                    # exactness is never traded for speed.
-                    obs.count("overflow_retry")
-                    capacity = (None
-                                if capacity is None or 2 * capacity >= b
-                                else 2 * capacity)
-
-            if algorithm in ("lloyd", "mb", "mbf"):
-                jax.block_until_ready(new_state.stats.C)
-                with audit.sanctioned_scope("round_info"):
-                    hinfo = fetch_round_info(info)
+            new_state, hinfo = step()
+            while hinfo.overflow:
+                # overflow retry (the lloyd and mb rounds never
+                # overflow): same input state, doubled bucket —
+                # exactness is never traded for speed.
+                obs.count("overflow_retry")
+                capacity = (None
+                            if capacity is None or 2 * capacity >= b
+                            else 2 * capacity)
+                new_state, hinfo = step()
             dt_s = time.perf_counter() - t0
             t_work += dt_s
             state = new_state
-            record(hinfo, dt_s)
-
+            with obs.span("round.record"):
+                record(hinfo, dt_s)
             if algorithm == "tb":
                 if bounds == "hamerly2":
                     need = -(-hinfo.n_recomputed // run.n_shards)
@@ -475,42 +444,46 @@ def run_loop(run: EngineRun, config: FitConfig, *,
                         obs.span("checkpoint"):
                     save_checkpoint()
 
-    if store is not None:
-        # one final save so a resumed-after-finish fit is a no-op loop
-        with obs.span("checkpoint"):
-            save_checkpoint()
-            if run.is_coordinator:
-                store.wait()
-        run.barrier()
+    with obs.span("fit.finish"):
+        if store is not None:
+            # one final save so a resumed-after-finish fit is a no-op
+            with obs.span("checkpoint"):
+                save_checkpoint()
+                if run.is_coordinator:
+                    store.wait()
+            run.barrier()
 
-    # final validation point (outside the timed region, like every eval),
-    # unless the last in-loop round already evaluated validation — a
-    # second eval at the same t would double-count it in the telemetry
-    if telemetry and telemetry[-1].val_mse is not None:
-        final = None
-    else:
-        final = run.eval_mse(state)
-    if final is not None:
-        # b is per-shard; b * n_shards includes the structural pad rows
-        # on a non-divisible mesh, so cap at the real dataset size
-        telemetry.append(Telemetry(
-            round=len(telemetry), t=t_work,
-            b=min(b * run.n_shards, run.n_points),
-            batch_mse=None, n_changed=0, n_recomputed=0, grow=False,
-            r_median=None, val_mse=final))
+        # final validation point (outside the timed region, like every
+        # eval), unless the last in-loop round already evaluated
+        # validation — a second eval at the same t would double-count
+        # it in the telemetry
+        if telemetry and telemetry[-1].val_mse is not None:
+            final = None
+        else:
+            final = run.eval_mse(state)
+        if final is not None:
+            # b is per-shard; b * n_shards includes the structural pad
+            # rows on a non-divisible mesh, so cap at the real size
+            telemetry.append(Telemetry(
+                round=len(telemetry), t=t_work,
+                b=min(b * run.n_shards, run.n_points),
+                batch_mse=None, n_changed=0, n_recomputed=0, grow=False,
+                r_median=None, val_mse=final))
 
-    obs.fit_end(rounds=len(telemetry), t_work=t_work, converged=converged)
+        obs.fit_end(rounds=len(telemetry), t_work=t_work,
+                    converged=converged, n_shards=run.n_shards)
 
-    # un-shuffle the final assignments back to the caller's row order;
-    # host_points is a gather collective on multi-process runs
-    a = np.asarray(run.host_points(state))
-    labels = np.full(run.n_points, -1, np.int32)
-    valid = run.orig_index >= 0
-    labels[run.orig_index[valid]] = a[valid]
+        # un-shuffle the final assignments back to the caller's row
+        # order; host_points is a gather collective on multi-process
+        # runs
+        a = np.asarray(run.host_points(state))
+        labels = np.full(run.n_points, -1, np.int32)
+        valid = run.orig_index >= 0
+        labels[run.orig_index[valid]] = a[valid]
 
-    stats = run.fetch_stats(state)
+        C = np.asarray(run.fetch_stats(state).C)
     plan = getattr(run, "kernel_plan", None)
-    return FitOutcome(C=np.asarray(stats.C), state=state,
+    return FitOutcome(C=C, state=state,
                       labels=labels, telemetry=telemetry,
                       converged=converged, algorithm=algorithm,
                       config=config,

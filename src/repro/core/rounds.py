@@ -425,8 +425,7 @@ def _assign_exponion(x, state, a_prev, valid, *, use_shalf: bool,
 
     ``n_recomputed`` counts actual pair-distance evaluations (annulus
     scans + the per-seen-point d_a refresh), the elkan convention — NOT
-    hamerly2's k-scan unit. `repro.obs.efficiency.WorkModel` prices the
-    two units accordingly.
+    hamerly2's k-scan unit.
 
     The optional ``geom`` / ``p_max`` / ``d_assigned`` overrides exist
     for the centroid-sharded engine (`core.distributed_xl`), which

@@ -50,8 +50,8 @@ def build_codebook(E, k: int, seed: int, *,
     exactly what a resuming operator does not want.
 
     ``trace_dir`` attaches a `repro.obs.FitObserver` to the fit: every
-    round's scalars, span timings and roofline utilization land as
-    JSONL under the directory (`python -m repro.obs summarize DIR`).
+    round's scalars and span timings land as JSONL under the
+    directory (`python -m repro.obs summarize DIR`).
 
     ``backend`` selects the execution engine for the FIT: "local"
     (default), "mesh" (points sharded over the host devices), "xl"

@@ -1,4 +1,4 @@
-"""repro.obs — structured tracing, unified metrics, roofline telemetry.
+"""repro.obs — structured tracing and unified metrics.
 
 One observability plane for the whole stack:
 
@@ -9,9 +9,6 @@ One observability plane for the whole stack:
     (``metrics.py``) — the registry generalized out of serve/metrics,
     with JSON and Prometheus-text exporters (`ServeMetrics` lives here
     now; ``repro.serve.metrics`` re-exports it);
-  * `WorkModel` (``efficiency.py``) — per-round achieved k-scans/s and
-    bytes/s against the ``roofline/analysis`` bound, exported as a live
-    utilization gauge;
   * `FitObserver` (``sink.py``) — the concrete sink behind
     ``FitConfig(trace_dir=...)`` that the host loop's `ObsSink` seam
     writes through.
@@ -20,7 +17,6 @@ The package imports NO jax and NO numpy: attaching it to the host loop
 cannot provoke a device sync (the hostsync auditor verifies this on
 every backend), and the reader CLI runs anywhere Python does.
 """
-from repro.obs.efficiency import FLOPS_PER_DIST, RoundWork, WorkModel
 from repro.obs.metrics import (Counter, Gauge, Histogram, LatencyHistogram,
                                MetricsRegistry, ServeMetrics)
 from repro.obs.sink import FitObserver
@@ -32,6 +28,5 @@ __all__ = [
     "trace_files",
     "Counter", "Gauge", "Histogram", "LatencyHistogram", "MetricsRegistry",
     "ServeMetrics",
-    "WorkModel", "RoundWork", "FLOPS_PER_DIST",
     "FitObserver",
 ]

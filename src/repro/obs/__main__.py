@@ -1,7 +1,7 @@
 """``python -m repro.obs`` — read traced fits from the command line.
 
   summarize DIR     one JSON summary of a trace directory (rounds,
-                    k-scans, span timings, retraces, utilization)
+                    k-scans, span timings, retraces)
   tail DIR [-n N]   the last N merged events, one JSON line each
   merge DIR [-o F]  merge per-process files into one time-ordered
                     JSONL stream (stdout or -o FILE)

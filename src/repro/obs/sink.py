@@ -1,11 +1,14 @@
 """`FitObserver` — the concrete obs sink a traced fit writes through.
 
-`api.loop.ObsSink` is the *seam*: a no-op base class `run_loop` and the
-engines call unconditionally. This module is the *implementation* wired
-in when a trace directory is configured: every round's host-landed
-scalars go to a `SpanTracer` JSONL stream, a `MetricsRegistry`
-aggregates counters/gauges/histograms for scraping, and a `WorkModel`
-prices each round against the roofline bound.
+`api.loop.ObsSink` is the *seam*: a base class `run_loop` and the
+engines call unconditionally, which writes nothing and opens only the
+profiler's annotation of each span. This module is the *implementation*
+wired in when a trace directory is configured: every round's host-landed
+scalars and every span of the fit go to a `SpanTracer` JSONL stream,
+and a `MetricsRegistry` aggregates counters/gauges/histograms for
+scraping. The host loop wraps it (`api.engines.base.ProfiledSink`) so
+each of its spans also opens the profiler's ``repro.<name>``
+annotation: the JSONL and a profiler trace name the same regions.
 
 The observer is deliberately **duck-typed** (it does not import
 `api.loop`): the obs package stays jax-free, so readers and CLIs run on
@@ -26,7 +29,6 @@ import time
 from pathlib import Path
 from typing import Any, Dict, Optional, Union
 
-from repro.obs.efficiency import WorkModel
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import OBS_SCHEMA, SpanTracer
 from repro.util import tracecount
@@ -49,20 +51,13 @@ class FitObserver:
       * ``trace-p<pid>-<seq>.jsonl``  — the span/event stream;
       * ``metrics-p<pid>.json``       — the registry export, at close.
 
-    ``k``/``d`` enable the roofline `WorkModel`; without them the
-    observer still traces rounds, just without priced work or the
-    utilization gauge. ``bounds`` selects the model's work unit:
-    elkan/exponion rounds count individual pair distances in
-    ``n_recomputed`` (annulus scans, not full k rows), and pricing them
-    as k-scans would overstate the work by exactly the pruning factor.
-    ``device_kind`` picks the chip's peaks; on a device with none
-    published (the CPU) the utilization gauge is not created and the
-    ``fit_start`` event carries the reason instead.
+    ``k``, ``d``, ``device_kind`` and ``meta`` describe the fit in the
+    ``fit_start`` event. Every counter counts host-landed values; the
+    fit's time per stage is in its spans.
     """
 
     def __init__(self, trace_dir: Union[str, Path], *, process_id: int = 0,
                  k: Optional[int] = None, d: Optional[int] = None,
-                 bounds: Optional[str] = None,
                  device_kind: Optional[str] = None,
                  meta: Optional[Dict[str, Any]] = None,
                  registry: Optional[MetricsRegistry] = None,
@@ -71,9 +66,6 @@ class FitObserver:
                                  rotate_bytes=rotate_bytes)
         self.registry = registry if registry is not None else \
             MetricsRegistry()
-        self.work = (WorkModel.for_bounds(k, d, bounds or "hamerly2",
-                                          device_kind=device_kind)
-                     if k and d else None)
         self._closed = False
         self._tc_before = tracecount.snapshot()
         self._store_before: Dict[str, Any] = {}
@@ -85,21 +77,10 @@ class FitObserver:
             "fit_jit_traces", "jit traces observed during the fit")
         self._round_s = r.histogram(
             "fit_round_seconds", "per-round wall time", unit="s")
-        self._g_kscans = r.gauge(
-            "fit_kscans_per_s", "last round's achieved k-scan rate")
-        self._g_bytes = r.gauge(
-            "fit_bytes_per_s", "last round's achieved HBM byte rate")
-        self._g_util = None
-        if self.work is not None and self.work.peaks is not None:
-            self._g_util = r.gauge(
-                "fit_roofline_utilization",
-                "last round's bound_s / wall_s vs the roofline model")
         self._g_b = r.gauge("fit_b_global", "current global nested batch")
         attrs = dict(meta or {})
         attrs.update(obs_schema=OBS_SCHEMA, k=k, d=d,
                      device_kind=device_kind)
-        if self.work is not None and self.work.no_roofline:
-            attrs["no_roofline"] = self.work.no_roofline
         self.tracer.event("fit_start", **attrs)
 
     # -- the ObsSink duck-type surface ---------------------------------------
@@ -109,7 +90,7 @@ class FitObserver:
         t0 = time.monotonic()
         with self.tracer.span(name, **attrs):
             yield
-        self.registry.histogram(f"fit_{name}_seconds",
+        self.registry.histogram(f"fit_span_{name}_seconds",
                                 f"{name} span wall time",
                                 unit="s").record(time.monotonic() - t0)
 
@@ -139,19 +120,6 @@ class FitObserver:
             "val_mse": _safe(float(val_mse)) if val_mse is not None
                        else None,
         }
-        if self.work is not None:
-            w = self.work.round_work(hinfo.n_recomputed, dt_s)
-            attrs.update(work_unit=w.unit,
-                         dist_evals=w.dist_evals, flops=w.flops,
-                         bytes=int(w.hbm_bytes),
-                         bound_s=_safe(w.bound_s),
-                         bottleneck=w.bottleneck,
-                         utilization=_safe(w.utilization))
-            if dt_s > 0.0:
-                self._g_kscans.set(w.kscans / dt_s)
-                self._g_bytes.set(w.hbm_bytes / dt_s)
-            if self._g_util is not None and w.utilization is not None:
-                self._g_util.set(w.utilization)
         if store:
             delta = {f"store_{key}": v - self._store_before.get(key, 0)
                      for key, v in store.items()

@@ -248,7 +248,7 @@ def tail_events(trace_dir: Union[str, Path], n: int = 20
 def summarize(events: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
     """Aggregate a merged event stream into one JSON-safe summary.
 
-    Round-level scalars (k-scans, bytes, retraces) are aggregated from
+    Round-level scalars (k-scans, retraces) are aggregated from
     the LOWEST process id only: `RoundInfo` is psum-reduced before it
     lands, so every process reports the same global values and summing
     across processes would multiply the work by the process count.
@@ -261,10 +261,9 @@ def summarize(events: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
     rounds_by_pid = {p: 0 for p in pids}
     summary: Dict[str, Any] = {
         "schema": OBS_SCHEMA, "processes": pids,
-        "rounds": 0, "kscans_total": 0, "dist_evals_total": 0,
-        "bytes_total": 0, "overflow_retries": 0, "jit_traces": 0,
-        "round_s_total": 0.0, "max_b_global": 0,
-        "utilization_last": None, "val_mse_last": None,
+        "rounds": 0, "kscans_total": 0, "overflow_retries": 0,
+        "jit_traces": 0, "round_s_total": 0.0, "max_b_global": 0,
+        "val_mse_last": None,
         "spans": {},
     }
     spans: Dict[str, Dict[str, Any]] = {}
@@ -289,13 +288,9 @@ def summarize(events: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
                 continue
             summary["rounds"] += 1
             summary["kscans_total"] += int(attrs.get("kscans", 0))
-            summary["dist_evals_total"] += int(attrs.get("dist_evals", 0))
-            summary["bytes_total"] += int(attrs.get("bytes", 0))
             summary["round_s_total"] += float(attrs.get("dt_s", 0.0))
             summary["max_b_global"] = max(summary["max_b_global"],
                                           int(attrs.get("b_global", 0)))
-            if attrs.get("utilization") is not None:
-                summary["utilization_last"] = attrs["utilization"]
             if attrs.get("val_mse") is not None:
                 summary["val_mse_last"] = attrs["val_mse"]
         elif name == "jit_trace" and pid == lead:

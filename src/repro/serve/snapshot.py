@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.api.engines.base import profiler_span
 from repro.kernels import ops, ref
 
 
@@ -107,17 +108,31 @@ class CodebookSnapshot:
 
     # -- inference (pure reads, safe from any thread) ------------------------
 
+    # A request opens ``repro.predict`` on the profiler's host plane,
+    # holding ``repro.predict.put`` (rows and codebook to the device),
+    # ``.dispatch`` (the jitted call) and ``.fetch`` (waiting for the
+    # device, and the answer to the host).
+
     def predict(self, X) -> np.ndarray:
         """Nearest-centroid index for each row of ``X``."""
-        a, _ = _predict_jit(jnp.asarray(X), jnp.asarray(self.centroids),
-                            backend=self.kernel_backend)
-        return np.asarray(a)
+        with profiler_span("predict"):
+            a, _ = self._assign(X)
+            with profiler_span("predict.fetch"):
+                return np.asarray(a)
 
     def predict_with_distance(self, X):
         """(labels, euclidean distance to the assigned centroid)."""
-        a, d1 = _predict_jit(jnp.asarray(X), jnp.asarray(self.centroids),
-                             backend=self.kernel_backend)
-        return np.asarray(a), np.asarray(np.sqrt(np.maximum(d1, 0.0)))
+        with profiler_span("predict"):
+            a, d1 = self._assign(X)
+            with profiler_span("predict.fetch"):
+                return (np.asarray(a),
+                        np.asarray(np.sqrt(np.maximum(d1, 0.0))))
+
+    def _assign(self, X):
+        with profiler_span("predict.put"):
+            X, C = jnp.asarray(X), jnp.asarray(self.centroids)
+        with profiler_span("predict.dispatch"):
+            return _predict_jit(X, C, backend=self.kernel_backend)
 
     def transform(self, X) -> np.ndarray:
         """Euclidean distance of each row to every centroid: (n, k)."""

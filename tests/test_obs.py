@@ -1,5 +1,5 @@
-"""The observability plane: tracer, registry, efficiency model, and the
-instrumented fit loop.
+"""The observability plane: tracer, registry, the instrumented fit loop,
+and the ``repro.*`` spans a profiler trace of a fit and a predict shows.
 
 The pure-python tests (tracer nesting/rotation/merge, histogram bounds,
 Prometheus export, registry semantics, Telemetry JSON round-trip) need
@@ -7,8 +7,11 @@ no jax at all — `repro.obs` imports neither jax nor numpy, and one test
 pins that property. The jax tests drive real traced fits: round events
 must match the loop's own schedule trace, `telemetry_` must round-trip
 through `to_dict`, and the host-sync auditor must stay SILENT with a
-`FitObserver` attached. The slow test runs scripts/smoke_obs.py, which
-repeats the traced fit + hostsync gate on mesh/xl/multihost.
+`FitObserver` attached. A tiny fit and three predicts run under
+`jax.profiler.trace`, with and without ``trace_dir``: the profiler's
+host plane holds the same ``repro.*`` spans either way, nested as the
+loop runs them. The slow test runs scripts/smoke_obs.py, which repeats
+the traced fit + hostsync gate on mesh/xl/multihost.
 """
 import json
 import math
@@ -20,8 +23,8 @@ import sys
 import pytest
 
 from repro.obs import (OBS_SCHEMA, Histogram, MetricsRegistry,
-                       ServeMetrics, SpanTracer, WorkModel, read_events,
-                       summarize, trace_files)
+                       ServeMetrics, SpanTracer, read_events, summarize,
+                       trace_files)
 
 
 # ---------------------------------------------------------------------------
@@ -184,22 +187,6 @@ def test_serve_metrics_schema_byte_compatible():
     assert Legacy is ServeMetrics
 
 
-def test_workmodel_prices_rounds():
-    from repro.roofline.analysis import V5E
-    w = WorkModel(k=50, d=64, device_kind=V5E)
-    rw = w.round_work(1000, dt_s=0.01)
-    assert rw.kscans == 1000 and rw.dist_evals == 50_000
-    assert rw.flops == 3.0 * 64 * 50_000
-    assert rw.hbm_bytes == 4 * (1000 * 64 + 50 * 64)
-    assert rw.bound_s > 0 and 0 < rw.utilization < 1
-    assert w.round_work(0).dist_evals == 0
-    # a device with no published peaks: counts only, and a stated reason
-    cpu = WorkModel(k=50, d=64, device_kind="cpu").round_work(1000, 0.01)
-    assert cpu.flops == rw.flops and cpu.hbm_bytes == rw.hbm_bytes
-    assert cpu.bound_s is None and cpu.utilization is None
-    assert "no published peaks" in WorkModel(k=50, d=64).no_roofline
-
-
 def test_obs_package_is_accelerator_free():
     code = ("import sys, repro.obs, repro.obs.sink, repro.obs.__main__; "
             "bad = [m for m in ('jax', 'numpy') if m in sys.modules]; "
@@ -240,9 +227,8 @@ def test_telemetry_json_roundtrip_nonfinite():
 @pytest.fixture(scope="module", params=["host", "v5e"])
 def traced_fit(request, tmp_path_factory):
     """A traced local fit. ``host``: the observer gets this process's
-    own device_kind (the CPU: no published peaks). ``v5e``: the test
-    hands it the v5e's device_kind, so rounds are priced against the
-    v5e roofline."""
+    own device_kind (the CPU). ``v5e``: the test hands it the v5e's,
+    which it records as it is."""
     import jax
     import numpy as np
 
@@ -270,10 +256,11 @@ def traced_fit(request, tmp_path_factory):
 
 
 def test_round_events_match_schedule_trace(traced_fit):
-    from repro.roofline.analysis import peaks_for
     td, out, schedule, kind = traced_fit
     ev = read_events(td)
-    rounds = [e for e in ev if e.get("name") == "round"]
+    # the "round" events; the "round" spans around them hold the stages
+    rounds = [e for e in ev
+              if e.get("ph") == "event" and e.get("name") == "round"]
     assert len(rounds) == len(schedule) > 0
     for e, s in zip(rounds, schedule):
         assert e["attrs"]["round"] == s["round"]
@@ -283,22 +270,15 @@ def test_round_events_match_schedule_trace(traced_fit):
     assert s["kscans_total"] == sum(r.n_recomputed for r in out.telemetry)
     start = next(e for e in ev if e.get("name") == "fit_start")
     assert start["attrs"]["device_kind"] == kind
-    if peaks_for(kind) is None:
-        # no chip peaks: no utilization, and the reason is on record
-        assert all(e["attrs"]["utilization"] is None for e in rounds)
-        assert "no published peaks" in start["attrs"]["no_roofline"]
-    else:
-        # the roofline gauge priced at least one round
-        assert all(e["attrs"]["utilization"] is None
-                   or 0 < e["attrs"]["utilization"] <= 1 for e in rounds)
-        assert any(e["attrs"]["utilization"] is not None for e in rounds)
-        assert "no_roofline" not in start["attrs"]
+    # rounds carry the host-landed scalars, and no priced work
+    assert all(e["attrs"]["kscans"] >= 0 for e in rounds)
+    assert not {"flops", "bytes", "utilization"} & {
+        key for e in rounds for key in e["attrs"]}
     names = {e.get("name") for e in ev}
     assert {"fit_start", "fit_end", "round"} <= names
 
 
 def test_metrics_json_written_at_close(traced_fit):
-    from repro.roofline.analysis import peaks_for
     td, out, schedule, kind = traced_fit
     path = td / "metrics-p00000.json"
     m = json.loads(path.read_text())
@@ -306,10 +286,12 @@ def test_metrics_json_written_at_close(traced_fit):
     assert m["counters"]["fit_kscans"] == sum(
         r.n_recomputed for r in out.telemetry)
     assert m["histograms"]["fit_round_seconds"]["count"] == len(schedule)
-    if peaks_for(kind) is None:
-        assert "fit_roofline_utilization" not in m["gauges"]
-    else:
-        assert 0 < m["gauges"]["fit_roofline_utilization"] <= 1
+    # the one gauge is the batch size, a host-landed value
+    assert set(m["gauges"]) == {"fit_b_global"}
+    # every span of the fit is timed under its own name: one info
+    # landing a round, and one more for each overflow retry
+    assert m["histograms"]["fit_span_round.info_seconds"]["count"] == (
+        len(schedule) + m["counters"].get("fit_overflow_retry", 0))
 
 
 def test_estimator_telemetry_roundtrip(tmp_path, blobs, blobs_val):
@@ -352,6 +334,121 @@ def test_hostsync_silent_with_tracing_on(tmp_path):
                                    trace_dir=str(tmp_path))
     assert found == []
     assert summarize(read_events(tmp_path))["rounds"] > 0
+
+
+# ---------------------------------------------------------------------------
+# the repro.* spans on the profiler's host plane
+# ---------------------------------------------------------------------------
+
+SINKS = ["default", "trace_dir"]
+SET_UP = ["repro.fit.shuffle", "repro.fit.to_device", "repro.fit.init"]
+ROUND = ["repro.round.dispatch", "repro.round.wait", "repro.round.info",
+         "repro.round.record"]
+PREDICT = ["repro.predict.put", "repro.predict.dispatch",
+           "repro.predict.fetch"]
+
+
+def _repro_spans(log_dir):
+    """``repro.*`` events of the trace's host plane: (name, start_ns,
+    end_ns), in time order."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+    xplane, = Path(log_dir).rglob("*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(str(xplane)).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            out += [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                    for e in line.events if e.name.startswith("repro.")]
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _inside(outer, spans, name=None):
+    """The spans (called ``name``) that lie in time inside ``outer``."""
+    _, lo, hi = outer
+    return [e for e in spans if e is not outer and lo <= e[1]
+            and e[2] <= hi and (name is None or e[0] == name)]
+
+
+@pytest.fixture(scope="module")
+def profiles(tmp_path_factory):
+    """A tiny tb/hamerly2 fit and 3 predicts under `jax.profiler.trace`,
+    once with the default sink and once with ``trace_dir`` set:
+    ``{sink: (repro.* spans, the fit's telemetry, trace_dir)}``."""
+    import jax
+    import numpy as np
+
+    from repro.api import FitConfig, NestedKMeans
+    from repro.serve import CodebookSnapshot
+
+    X = np.random.default_rng(0).normal(size=(2048, 16)).astype(np.float32)
+    base = dict(k=8, algorithm="tb", bounds="hamerly2", b0=256, seed=0,
+                max_rounds=12, capacity_floor=32)
+    NestedKMeans(FitConfig(**base)).fit(X)       # compile outside
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    out = {}
+    for sink in SINKS:
+        td = (str(tmp_path_factory.mktemp("jsonl"))
+              if sink == "trace_dir" else None)
+        km = NestedKMeans(FitConfig(**base, trace_dir=td))
+        log_dir = tmp_path_factory.mktemp(f"profile-{sink}")
+        with jax.profiler.trace(str(log_dir), profiler_options=opts):
+            km.fit(X)
+            snap = CodebookSnapshot.create(1, km.export_codebook())
+            for i in range(3):
+                snap.predict(X[16 * i:16 * (i + 1)])
+        out[sink] = (_repro_spans(log_dir), km.telemetry_, td)
+    return out
+
+
+@pytest.mark.parametrize("sink", SINKS)
+def test_profiler_shows_the_fit_set_up_and_finish(profiles, sink):
+    spans, telemetry, _ = profiles[sink]
+    begin, = [e for e in spans if e[0] == "repro.fit.begin"]
+    inner = _inside(begin, spans)
+    assert [e[0] for e in inner] == SET_UP
+    finish, = [e for e in spans if e[0] == "repro.fit.finish"]
+    last_round = [e for e in spans if e[0] == "repro.round"][-1]
+    assert begin[2] <= last_round[1] and last_round[2] <= finish[1]
+
+
+@pytest.mark.parametrize("sink", SINKS)
+def test_profiler_shows_each_round_and_its_stages(profiles, sink):
+    spans, telemetry, _ = profiles[sink]
+    rounds = [e for e in spans if e[0] == "repro.round"]
+    assert len(rounds) == len(telemetry) > 1
+    for r in rounds:
+        assert len(_inside(r, spans, "repro.round.info")) == 1
+        stages = [e[0] for e in _inside(r, spans) if e[0] in ROUND]
+        assert stages == ROUND
+
+
+@pytest.mark.parametrize("sink", SINKS)
+def test_profiler_shows_each_predict_and_its_stages(profiles, sink):
+    spans, _, _ = profiles[sink]
+    predicts = [e for e in spans if e[0] == "repro.predict"]
+    assert len(predicts) == 3
+    for p in predicts:
+        assert [e[0] for e in _inside(p, spans)] == PREDICT
+
+
+def test_profiler_names_are_the_same_with_and_without_trace_dir(profiles):
+    names = {sink: sorted({e[0] for e in profiles[sink][0]})
+             for sink in SINKS}
+    assert names["default"] == names["trace_dir"]
+    assert {"repro.fit.begin", "repro.round", "repro.fit.finish",
+            "repro.predict"} <= set(names["default"])
+
+
+def test_jsonl_holds_the_fit_spans_under_the_same_names(profiles):
+    spans, _, td = profiles["trace_dir"]
+    jsonl = [e["name"] for e in read_events(td) if e.get("ph") == "span"]
+    fit = [e[0][len("repro."):] for e in spans
+           if not e[0].startswith("repro.predict")]
+    assert sorted(jsonl) == sorted(fit)
 
 
 def test_cli_summarize_and_tail(tmp_path, capsys):
