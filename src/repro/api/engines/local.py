@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import dataclasses
+from typing import Mapping, Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +34,40 @@ _lloyd_jit = jax.jit(rounds.lloyd_round, static_argnames=("plan",))
 # for full segments plus a handful of ragged tails
 _IO_SEG_ROWS = 65536
 
+# the in-memory fit's shuffle: storage row i <- X[perm[i]], gathered on
+# the device from the rows as the caller gave them. One executable per
+# (N, d); a copy of f32 rows, so bit-equal to the host's X[perm].
+# (`jnp.take` has no in-bounds mode; this is its gather with one)
+_take_rows = jax.jit(lambda X, perm: X.at[perm].get(
+    mode="promise_in_bounds", unique_indices=True))
+
+
+def gather_path(need_bytes: int, stats: Optional[Mapping[str, int]]) -> str:
+    """Where an in-memory fit shuffles its rows: ``"device"`` when the
+    gather's ``need_bytes`` fit in what the device has free by its
+    ``memory_stats()`` ``stats``, ``"host"`` otherwise. A backend that
+    reports no statistics (the CPU) gathers on the device."""
+    if not stats or "bytes_limit" not in stats:
+        return "device"
+    free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+    return "device" if need_bytes <= free else "host"
+
+
+def _memory_stats() -> Optional[Mapping[str, int]]:
+    """The default device's ``memory_stats()`` (None on the CPU)."""
+    return jax.devices()[0].memory_stats()
+
+
+def _gather_bytes(X: np.ndarray, perm: np.ndarray) -> int:
+    """Device bytes `_take_rows` holds at its peak on these shapes: the
+    unshuffled rows, the shuffled rows, and its temporaries (on a TPU,
+    the layout copies around the gather: more than the rows again).
+    Compiles on the first fit of a shape and reads the jit's cache
+    after."""
+    m = _take_rows.lower(X, perm).compile().memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes)
+
 
 class _LocalRun(EngineRun):
     def __init__(self, X, config: FitConfig, X_val, init_C, obs=None):
@@ -54,8 +89,14 @@ class _LocalRun(EngineRun):
                 N = X.shape[0]
                 perm = (rng.permutation(N) if config.shuffle
                         else np.arange(N))
-                rows = X[perm]
-        with self._obs.span("fit.to_device"):
+        # where the in-memory shuffle gathers its rows (None: no gather)
+        gather = None
+        if self._store is None and config.shuffle:
+            perm32 = perm.astype(np.int32)
+            stats = _memory_stats()
+            gather = gather_path(
+                _gather_bytes(X, perm32) if stats else 0, stats)
+        with self._obs.span("fit.to_device", gather=gather):
             if self._store is not None:
                 # a zero device buffer filled lazily to the current
                 # nested prefix (`_ensure_prefix`); the host never holds
@@ -67,8 +108,16 @@ class _LocalRun(EngineRun):
                 self._filled = 0
                 self._upd = piece_update
             else:
-                self._Xd = jnp.asarray(rows)
-                del rows
+                if gather == "device":
+                    # the rows go over as they are; the put of the
+                    # unshuffled buffer is dropped once the gather that
+                    # reads it is dispatched
+                    self._Xd = _take_rows(jnp.asarray(X),
+                                          jnp.asarray(perm32))
+                else:
+                    # "host": too little device memory for the gather
+                    self._Xd = jnp.asarray(X if gather is None
+                                           else X[perm])
                 self._filled = N
             self._Xv = jnp.asarray(X_val) if X_val is not None else None
         self._config = config

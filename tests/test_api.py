@@ -350,3 +350,109 @@ def test_outcome_carries_config(blobs):
     # outcome records the RESOLVED config (canonical algorithm)
     assert out.config.algorithm == "tb" and out.config.bounds == "none"
     assert out.config.k == 8
+
+
+# ---------------------------------------------------------------------------
+# the in-memory shuffle: gathered on the device, or on the host when the
+# device has too little memory free for the gather
+# ---------------------------------------------------------------------------
+
+def _unshuffled_twin(X, cfg):
+    """`api.fit` on ``X`` and on ``X`` shuffled by the fit's own
+    permutation with the fit's shuffle off: (outcome, twin, perm)."""
+    perm = np.random.default_rng(cfg.seed).permutation(len(X))
+    twin = api.fit(X[perm], dataclasses.replace(cfg, shuffle=False))
+    return api.fit(X, cfg), twin, perm
+
+
+def _telemetry_without_t(out):
+    return [{k: v for k, v in r.to_dict().items() if k != "t"}
+            for r in out.telemetry]
+
+
+@pytest.mark.parametrize("algorithm,bounds",
+                         [("tb", "hamerly2"), ("gb", "none")])
+def test_shuffled_fit_bit_equal_to_prepermuted_rows(blobs, algorithm,
+                                                    bounds):
+    """The device gather lays the rows out exactly as ``X[perm]``."""
+    X, _ = blobs
+    cfg = api.FitConfig(k=8, algorithm=algorithm, bounds=bounds, b0=256,
+                        max_rounds=60, seed=5)
+    out, twin, perm = _unshuffled_twin(X, cfg)
+    np.testing.assert_array_equal(out.C, twin.C)
+    np.testing.assert_array_equal(out.labels[perm], twin.labels)
+    assert _telemetry_without_t(out) == _telemetry_without_t(twin)
+
+
+ROWS_BYTES = 4000 * 16 * 4
+
+
+@pytest.mark.parametrize("need,stats,path", [
+    (2 * ROWS_BYTES, {"bytes_limit": 3 * ROWS_BYTES, "bytes_in_use": 0},
+     "device"),
+    (2 * ROWS_BYTES, {"bytes_limit": 3 * ROWS_BYTES,
+                      "bytes_in_use": 2 * ROWS_BYTES}, "host"),
+    (2 * ROWS_BYTES, {"bytes_limit": ROWS_BYTES}, "host"),
+    (2 * ROWS_BYTES, {"bytes_limit": 2 * ROWS_BYTES, "bytes_in_use": 0},
+     "device"),
+    (2 * ROWS_BYTES, None, "device"),
+    (2 * ROWS_BYTES, {}, "device"),
+])
+def test_gather_path_decision(need, stats, path):
+    from repro.api.engines.local import gather_path
+    assert gather_path(need, stats) == path
+
+
+@pytest.mark.parametrize("limit,path", [(1024, "host"), (1 << 40, "device")])
+def test_gather_path_from_memory_stats(blobs, monkeypatch, tmp_path, limit,
+                                       path):
+    """A device that reports its memory decides by the gather's own
+    compiled footprint; either branch fits bit-equal to the CPU's
+    (no statistics: device), and the trace names the branch taken."""
+    from repro.api.engines import local
+    from repro.obs import read_events
+    X, _ = blobs
+    cfg = api.FitConfig(k=8, b0=256, max_rounds=30, seed=3)
+    ref = api.fit(X, cfg)
+    monkeypatch.setattr(local, "_memory_stats",
+                        lambda: {"bytes_limit": limit, "bytes_in_use": 0})
+    out = api.fit(X, dataclasses.replace(cfg, trace_dir=str(tmp_path)))
+    np.testing.assert_array_equal(out.C, ref.C)
+    np.testing.assert_array_equal(out.labels, ref.labels)
+    assert _telemetry_without_t(out) == _telemetry_without_t(ref)
+    to_device, = [e for e in read_events(tmp_path)
+                  if e.get("name") == "fit.to_device"]
+    assert to_device["attrs"] == {"gather": path}
+
+
+def test_gather_bytes_counts_both_copies():
+    from repro.api.engines.local import _gather_bytes
+    X = np.zeros((1000, 16), np.float64)     # put as f32
+    perm = np.arange(1000, dtype=np.int32)
+    assert _gather_bytes(X, perm) >= 2 * 1000 * 16 * 4 + perm.nbytes
+
+
+def test_second_fit_of_a_shape_compiles_nothing():
+    """Every executable of an in-memory fit, the shuffle's gather among
+    them, is keyed on shapes: a second fit of the same shape compiles
+    nothing (the benchmark's ``compiles_in_window.fit`` reads 0)."""
+    import jax.monitoring as mon
+    compiles = []
+
+    def listen(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    # a shape no other test fits, so the first fit compiles the gather
+    X = np.random.default_rng(7).normal(size=(1531, 12)).astype(np.float32)
+    cfg = api.FitConfig(k=6, b0=128, max_rounds=25, seed=1)
+    mon.register_event_duration_secs_listener(listen)
+    try:
+        first = api.fit(X, cfg)
+        n_first = len(compiles)
+        second = api.fit(X, dataclasses.replace(cfg, seed=2))
+    finally:
+        mon.unregister_event_duration_listener(listen)
+    assert n_first > 0
+    assert len(compiles) == n_first
+    assert first.telemetry and second.telemetry
