@@ -147,6 +147,10 @@ class EngineRun:
     #: engines predating the dispatch plane); surfaced in `FitOutcome`
     #: and the benchmark manifests.
     kernel_plan: Optional[Any] = None
+    #: whether ``nested_step`` takes a compacted hamerly2 round's delta
+    #: S/v over the compacted buffer alone (`core.rounds.nested_round`);
+    #: `run_loop` counts those rounds as ``round.delta_compacted``.
+    delta_over_buffer: bool = True
 
     # -- round executors (pure: state in -> (state, info)) ------------------
 

@@ -19,6 +19,8 @@ class _XLRun(_MeshRun):
     placement and the compiled round differ.
     """
     _engine_name = "xl"
+    # the sharded round keeps its own full-prefix delta (`_delta_sv_xl`)
+    delta_over_buffer = False
 
     def __init__(self, X, config: FitConfig, mesh, X_val, init_C):
         if config.model_axis not in mesh.shape:

@@ -233,7 +233,10 @@ def run_loop(run: EngineRun, config: FitConfig, *,
     hook). ``None`` uses the no-op scopes.
 
     ``obs``: optional sink receiving each round's host-landed scalars,
-    span timings and overflow-retry counts — usually a
+    span timings, overflow-retry counts and the count of dispatches
+    that take the delta S/v over hamerly2's compacted buffer
+    (``round.delta_compacted``; an overflow retry that still compacts
+    counts again) — usually a
     `repro.obs.FitObserver`, wrapped by `as_sink` so its spans also
     reach the profiler. ``None`` uses the default sink, whose spans
     reach the profiler alone. The spans: ``round`` (one iteration of
@@ -371,6 +374,9 @@ def run_loop(run: EngineRun, config: FitConfig, *,
                     state, fixed=(algorithm == "mbf"))
             else:  # tb family (incl. gb via bounds="none")
                 new_state, info = run.nested_step(state, b, capacity)
+                if (bounds == "hamerly2" and capacity is not None
+                        and capacity < b and run.delta_over_buffer):
+                    obs.count("round.delta_compacted")
         with obs.span("round.wait"):
             jax.block_until_ready(new_state.stats.C)
         with audit.sanctioned_scope("round_info"), obs.span("round.info"):

@@ -274,7 +274,7 @@ def _fused_dense_round(x, state, a_prev, valid, *, bounds: str,
     tb with capacity covering the batch); the compacted tb path keeps
     the separate kernels because its gather/scatter breaks the
     single-sweep structure. Returns the `_assign_*` 6-tuple plus the
-    fused (dS, dv, sse) accumulators via the normally-unused last slot.
+    fused (dS, dv, sse) accumulators in the last slot.
     """
     b = x.shape[0]
     if bounds == "hamerly2":
@@ -312,6 +312,9 @@ def _assign_hamerly2(x, state, a_prev, valid, *, capacity: Optional[int],
     recompute the round reports overflow=True and the driver retries the
     same input state with a larger bucket — exactness is never sacrificed.
     ``capacity=None`` recomputes everything (used for b == capacity).
+    A compacted round hands back its buffer ``(x_cap, idx_cap)`` in the
+    last slot (None otherwise): only those rows can change cluster, so
+    the delta S/v need not read the rest of the prefix.
 
     The optional ``p_max`` / ``d_assigned`` / ``s_half`` /
     ``assign_top2_fn`` overrides exist for the centroid-sharded engine
@@ -353,7 +356,8 @@ def _assign_hamerly2(x, state, a_prev, valid, *, capacity: Optional[int],
     d_new = d_new.at[idx_cap].set(d1)
     lb_new = lb_new.at[idx_cap].set(d2)
     overflow = n_need > capacity
-    return a_new, d_new, lb_new, jnp.minimum(n_need, capacity), overflow, None
+    return (a_new, d_new, lb_new, jnp.minimum(n_need, capacity), overflow,
+            (x_cap, idx_cap))
 
 
 def _assign_elkan(x, state, a_prev, valid, *, b: int):
@@ -519,6 +523,7 @@ def nested_round(X: jax.Array, state: KMeansState, *, b: int,
                   or (bounds == "hamerly2"
                       and (capacity is None or capacity >= b))))
     fused_acc = None
+    buffer = None
     if fused:
         a_new, d_new, lb2, n_rec, overflow, fused_acc = \
             _fused_dense_round(x, state, a_prev, valid, bounds=bounds,
@@ -528,9 +533,10 @@ def nested_round(X: jax.Array, state: KMeansState, *, b: int,
         a_new, d_new, lb2, n_rec, overflow, l_new = _assign_exhaustive(
             x, state, a_prev, valid, plan=plan)
     elif bounds == "hamerly2":
-        a_new, d_new, lb2, n_rec, overflow, l_new = _assign_hamerly2(
+        a_new, d_new, lb2, n_rec, overflow, buffer = _assign_hamerly2(
             x, state, a_prev, valid, capacity=capacity,
             use_shalf=use_shalf, plan=plan)
+        l_new = None
     elif bounds == "elkan":
         a_new, d_new, lb2, n_rec, overflow, l_new = \
             _assign_elkan(x, state, a_prev, valid, b=b)
@@ -553,7 +559,15 @@ def nested_round(X: jax.Array, state: KMeansState, *, b: int,
     if fused_acc is not None:
         dS, dv, sse = fused_acc
     else:
-        dS, dv = _delta_sv(x, a_prev, a_new, k, plan)
+        if buffer is not None:
+            # every row outside hamerly2's compacted buffer keeps
+            # a_new == a_prev and weighs 0 in both passes (idx_cap holds
+            # unique rows; a masked pad in it has a_new == -1)
+            x_cap, idx_cap = buffer
+            dS, dv = _delta_sv(x_cap, a_prev[idx_cap], a_new[idx_cap], k,
+                               plan)
+        else:
+            dS, dv = _delta_sv(x, a_prev, a_new, k, plan)
         sse = _refresh_sse(d_new, a_new, k)
     mse_num = jnp.sum(d_new * d_new)
     mse_den = (jnp.asarray(b, jnp.float32) if valid is None

@@ -7,6 +7,8 @@ The paper's central exactness claims:
   * gb-inf with b0=N reproduces Lloyd's algorithm.
 """
 
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -142,6 +144,47 @@ def test_capacity_compaction_is_exact(blobs):
         cap = 256   # deliberately small -> exercises retry next round
         np.testing.assert_array_equal(np.asarray(s_a.points.a[:b]),
                                       np.asarray(s_b.points.a[:b]))
+
+
+@pytest.mark.parametrize("capacity", [512, 1016])
+@pytest.mark.parametrize("backend,tol", [("ref", 1e-6), ("pallas", 1e-5)])
+def test_compacted_delta_matches_full_prefix(blobs, backend, tol, capacity):
+    """A compacted hamerly2 round takes its delta S/v over the capacity
+    buffer alone; it must equal the delta over the whole prefix. The
+    prefix ends in pad rows (``n_valid < b``), and settled rows fill the
+    buffer after the rows that need a rescan: at capacity 1016 the pads
+    fill its tail too. The labels are ``bounds="none"``'s."""
+    from repro.core.state import centroid_update
+    from repro.kernels.plan import resolve_plan
+
+    X, _ = blobs
+    k, b, n_valid = 8, 1024, jnp.int32(1000)
+    Xd = jnp.asarray(X)
+    plan = resolve_plan(backend, b=b, k=k, d=X.shape[1], bounds="hamerly2")
+    s = init_state(Xd, k, bounds="hamerly2")
+    for _ in range(3):
+        s, _ = rounds.nested_round(Xd, s, b=b, rho=np.inf, bounds="hamerly2",
+                                   plan=plan, n_valid=n_valid)
+    s_c, info = rounds.nested_round(Xd, s, b=b, rho=np.inf,
+                                    bounds="hamerly2", capacity=capacity,
+                                    plan=plan, n_valid=n_valid)
+    assert not bool(info.overflow)
+    assert 0 < int(info.n_recomputed) < capacity     # settled rows fill it
+    assert int(info.n_changed) > 0
+
+    s_none, _ = rounds.nested_round(Xd, s, b=b, rho=np.inf, bounds="none",
+                                    plan=plan, n_valid=n_valid)
+    np.testing.assert_array_equal(np.asarray(s_c.points.a[:b]),
+                                  np.asarray(s_none.points.a[:b]))
+
+    dS, dv = rounds._delta_sv(Xd[:b], s.points.a[:b], s_c.points.a[:b], k,
+                              plan)
+    want = centroid_update(dataclasses.replace(
+        s.stats, S=s.stats.S + dS, v=s.stats.v + dv))
+    for name in ("S", "v", "C"):
+        np.testing.assert_allclose(np.asarray(getattr(s_c.stats, name)),
+                                   np.asarray(getattr(want, name)),
+                                   rtol=tol, atol=tol, err_msg=name)
 
 
 @pytest.mark.parametrize("b,capacity,p", [

@@ -294,6 +294,37 @@ def test_metrics_json_written_at_close(traced_fit):
         len(schedule) + m["counters"].get("fit_overflow_retry", 0))
 
 
+@pytest.mark.parametrize("bounds", ["hamerly2", "none"])
+def test_compacted_delta_rounds_are_counted(tmp_path, blobs, bounds):
+    """Each dispatch of a hamerly2 round whose capacity is below its
+    batch takes the delta S/v over the compacted buffer, and counts
+    once: the rounds whose events show ``capacity < b``, and each
+    overflow retry (only a compacted dispatch overflows). A fit without
+    hamerly2 counts none."""
+    from repro.api.config import FitConfig
+    from repro.api.engines import make_engine
+    from repro.api.loop import run_loop
+    from repro.obs import FitObserver
+
+    X, _ = blobs
+    n, d = X.shape
+    config = FitConfig(k=8, b0=256, seed=0, max_rounds=30, bounds=bounds,
+                       capacity_floor=32).resolve(n)
+    run = make_engine(config).begin(X, config)
+    with FitObserver(tmp_path, k=8, d=d,
+                     meta={"backend": "local"}) as obs:
+        run_loop(run, config, obs=obs)
+    counters = json.loads(
+        (tmp_path / "metrics-p00000.json").read_text())["counters"]
+    rounds = [e["attrs"] for e in read_events(tmp_path)
+              if e.get("ph") == "event" and e.get("name") == "round"]
+    compacted = sum(r["capacity"] is not None
+                    and r["capacity"] < r["b_global"] for r in rounds)
+    assert (compacted > 0) == (bounds == "hamerly2")
+    assert counters.get("fit_round.delta_compacted", 0) == (
+        compacted + counters.get("fit_overflow_retry", 0))
+
+
 def test_estimator_telemetry_roundtrip(tmp_path, blobs, blobs_val):
     import dataclasses
 

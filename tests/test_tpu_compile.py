@@ -4,8 +4,9 @@ The Pallas kernels run in interpret mode everywhere else in the suite,
 which accepts tiles and VMEM footprints the chip's compiler refuses.
 Here the real compiler lowers them at the infMNIST width (d=784) and a
 65,536-row batch, with the blocks the default plan picks, for the paper's
-k=50 and the over-segmented k=1024, and the predict path at three request
-sizes. Nothing runs: a pass says the chip's compiler accepts the program.
+k=50 and the over-segmented k=1024, a compacted nested round at k=50, and
+the predict path at three request sizes. Nothing runs: a pass says the
+chip's compiler accepts the program.
 
 The topology is described inside a fixture, never at import time, so
 every pytest-xdist worker collects the same tests and only the worker
@@ -22,7 +23,10 @@ B, D = 65536, 784
 def one_chip():
     """A single-device sharding on a described v5e chip, with JAX's
     persistent compilation cache off: a compile for a device that is not
-    attached is written to it but cannot be read back."""
+    attached is written to it but cannot be read back. On the way out
+    the in-memory caches are cleared too: the predict trace made while
+    ``jax.default_backend`` reads "tpu" holds a compiled-mode Pallas call,
+    and a later call of the same shapes on the CPU would reuse it."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
     from jax.experimental import topologies
@@ -38,6 +42,7 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     yield SingleDeviceSharding(topo.devices[0])
+    jax.clear_caches()
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
 
@@ -84,6 +89,30 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, k):
                                                      plan=plan))
         args = (x, rows_i, rows_f)
     _assert_mosaic(fn.lower(*args).compile())
+
+
+def test_compacted_round_pads_no_whole_prefix(one_chip):
+    """A compacted hamerly2 round (capacity 8,192 of a 65,536-row
+    prefix, k=50) takes its delta S/v over the capacity buffer, so the
+    chip's program holds no copy of the whole prefix padded to the
+    cluster-sum kernel's 1,024 features."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.api.engines.local import nested_jit
+    from repro.core.state import init_state
+
+    k = 50
+    x = _spec((B, D), jnp.float32, one_chip)
+    state = jax.tree.map(
+        lambda a: _spec(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda X: init_state(X, k, bounds="hamerly2"), x))
+    compiled = nested_jit.lower(
+        x, state, b=B, rho=np.inf, bounds="hamerly2", capacity=8192,
+        plan=_tpu_plan(k)).compile()
+    _assert_mosaic(compiled)
+    assert f"f32[{B},1024]" not in compiled.as_text()
 
 
 @pytest.mark.parametrize("rows", [1, 256, 2048])
